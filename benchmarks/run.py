@@ -302,6 +302,8 @@ def main() -> None:
     ap.add_argument("--profile-dir", default="artifacts/profile",
                     help="trace output directory for --profile")
     args = ap.parse_args()
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache(_ROOT / ".jax_cache")
     if args.check:
         sys.exit(1 if check(args.tolerance) else 0)
     if args.profile:

@@ -61,6 +61,7 @@ from repro.core.scheduler import DeviceEngine, _make_engine
 from repro.core.types import Allocation, ARRequest, Policy, T_INF
 from repro.launch.mesh import data_shards, resolve_placement
 from repro.sharding import rules as shard_rules
+from repro.tenancy.telemetry import _PER_TENANT, to_host
 
 
 class OfferResult:
@@ -354,6 +355,11 @@ class Session:
     def metrics(self, tenant: Optional[int] = None) -> Dict[str, Any]:
         """Admission counters plus capacity / streaming geometry.
 
+        Device sessions report ``search_path``: ``"kernel"`` when their
+        searches run the Pallas kernel, ``"jnp"`` when they run the
+        jnp reference (``use_kernel=False``, or a timeline beyond the
+        kernel's budget).
+
         On multi-tenant sessions the ``"tenants"`` key carries the
         per-tenant telemetry arrays (weights, quotas, usage, live
         counts, acceptance/slowdown EWMAs — DESIGN.md §10), read in
@@ -483,6 +489,18 @@ class _BackendBase:
 
     def _donate_ok(self) -> bool:
         return self.cfg.donate and not self._retained
+
+    def _search_path(self, capacity: int, n_pe: int) -> str:
+        """The candidate search a dispatch at ``capacity`` runs: the
+        Pallas kernel when the config asks for it and the shape is
+        within the kernel's budget, else the jnp reference.  Capacity
+        only grows, so ``"kernel"`` now means every dispatch so far
+        ran the kernel."""
+        from repro.kernels import ops as kernel_ops
+        if self.cfg.use_kernel and kernel_ops.fits(
+                capacity, n_pe, self.cfg.rspec):
+            return "kernel"
+        return "jnp"
 
     def _defer_accepted(self, decision, valid) -> None:
         """Accumulate the accepted count on-device, no host sync.
@@ -1002,14 +1020,12 @@ class _StreamBackend(_BackendBase):
                 n_parked=s.n_parked, n_promoted=s.n_promoted,
                 n_moved=s.n_moved)
         if s.tenants is not None:
-            from repro.tenancy.telemetry import _PER_TENANT
             vals["tenants"] = {
                 f: getattr(s.tenants, f)
                 for f in _PER_TENANT + ("occ_ewma",)}
         host = _device_fetch(vals)
         self._dev_metrics = {
-            k: ({kk: np.asarray(vv) for kk, vv in v.items()}
-                if k == "tenants" else int(v))
+            k: to_host(v) if k == "tenants" else int(v)
             for k, v in host.items()}
 
     def metrics(self):
@@ -1022,7 +1038,8 @@ class _StreamBackend(_BackendBase):
         if self._dev_metrics is None:
             self._refresh_dev_metrics()
         cap, pend = self._capacities()
-        out = dict(capacity=cap, pending_capacity=pend)
+        out = dict(capacity=cap, pending_capacity=pend,
+                   search_path=self._search_path(cap, self.cfg.n_pe))
         out.update(self._dev_metrics)
         if self.ring:
             out.update(ring_capacity=self.ring.capacity,
@@ -1442,14 +1459,12 @@ class _EnsembleBackend(_BackendBase):
                 n_promoted=jnp.sum(s.n_promoted),
                 n_moved=jnp.sum(s.n_moved))
         if s.tenants is not None:
-            from repro.tenancy.telemetry import _PER_TENANT
             vals["tenants"] = {
                 f: getattr(s.tenants, f)
                 for f in _PER_TENANT + ("occ_ewma",)}
         host = _device_fetch(vals) if vals else {}
         self._dev_metrics = {
-            k: ({kk: np.asarray(vv) for kk, vv in v.items()}
-                if k == "tenants" else int(v))
+            k: to_host(v) if k == "tenants" else int(v)
             for k, v in host.items()}
 
     def metrics(self):
@@ -1458,6 +1473,7 @@ class _EnsembleBackend(_BackendBase):
             self._refresh_dev_metrics()
         cap, pend = self._capacities()
         out = dict(capacity=cap, pending_capacity=pend,
+                   search_path=self._search_path(cap, self.cfg.n_pe),
                    placement_shards=data_shards(self.mesh)
                    if self.mesh is not None else 1)
         out.update(self._dev_metrics)
@@ -1540,7 +1556,7 @@ class _PartitionBackend(_BackendBase):
         post-release population), then the float32 quota /
         concurrency check, then routing for requests that pass.
         Occupancy EWMA is not tracked at the router (no single
-        machine occupancy exists across partitions): ``occ_frac=0``.
+        machine occupancy exists across partitions): ``occ_q=0``.
         """
         acc = self._accounts
         allocs: List[Optional[Allocation]] = []
@@ -1555,14 +1571,13 @@ class _PartitionBackend(_BackendBase):
             tid = acc.clip_tid(req.tenant)
             if not acc.allowed(tid, req.n_pe, req.t_du):
                 acc.record(tid, accepted=False, blocked=True,
-                           parked=False, occ_frac=np.float32(0.0))
+                           parked=False)
                 allocs.append(None)
                 continue
             alloc = self.engine.admit_stream_allocations(
                 [req], pol, routing)[0]
             acc.record(tid, accepted=alloc is not None,
                        blocked=False, parked=False,
-                       occ_frac=np.float32(0.0),
                        t_e=alloc.t_e if alloc else -1,
                        t_r=req.t_r, t_du=req.t_du, n_pe=req.n_pe)
             if alloc is not None:
@@ -1676,6 +1691,8 @@ class _PartitionBackend(_BackendBase):
     def metrics(self):
         cap, pend = ens_lib.lane_capacity(self.engine.states)
         out = dict(capacity=cap, pending_capacity=pend,
+                   search_path=self._search_path(
+                       cap, self.engine.chips_per_part),
                    chips_per_partition=self.engine.chips_per_part,
                    partition_load=list(self.engine.load),
                    dispatches=self.engine.dispatches,

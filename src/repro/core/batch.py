@@ -909,8 +909,9 @@ def _admit_impl(state: SchedulerState, req: RequestBatch,
             # telemetry stays a PE-utilisation fraction: count only
             # the primary plane's words of the multi-resource row
             occ_row = occ_row[state.rspec.plane_slice(0)]
-        occ_frac = (jax.lax.population_count(occ_row).sum()
-                    .astype(jnp.float32) / jnp.float32(n_pe))
+        occ_q = tenancy_lib.ratio_q16(
+            jax.lax.population_count(occ_row).sum().astype(jnp.int32),
+            n_pe)
         within = ((tn0.used[tid] + demand <= tn0.quota[tid])
                   & (tn0.live[tid] < tn0.max_live[tid]))
         blocked = real & ~within
@@ -1069,23 +1070,21 @@ def _admit_impl(state: SchedulerState, req: RequestBatch,
         # nothing read back).  Filler padding (real=False) and
         # overflowed steps (re-run from the pre-run snapshot anyway)
         # charge nothing, so the table matches the host oracle, which
-        # sees neither.  Expression shapes mirror
-        # HostTenantAccounts.record float32-for-float32.
+        # sees neither.  `used` mirrors HostTenantAccounts.record
+        # float32-for-float32; the EWMAs are int32 fixed point.
         tn = state.tenants
         ok_upd = real & ~state.overflow
-        one = jnp.float32(1.0)
         a = tn.alpha
         acc_i = jnp.where(ok_upd & accepted, 1, 0).astype(jnp.int32)
         rej_i = jnp.where(ok_upd & ~accepted, 1, 0).astype(jnp.int32)
         qrej_i = jnp.where(ok_upd & blocked, 1, 0).astype(jnp.int32)
         prk_i = jnp.where(ok_upd & accepted & parks, 1,
                           0).astype(jnp.int32)
-        acc_x = jnp.where(accepted, one, jnp.float32(0.0))
-        new_acc = tn.acc_ewma[tid] * (one - a) + acc_x * a
-        slow_x = ((t_e - orig_tr).astype(jnp.float32)
-                  / orig_tdu.astype(jnp.float32))
-        new_slow = tn.slow_ewma[tid] * (one - a) + slow_x * a
-        new_occ = tn.occ_ewma * (one - a) + occ_frac * a
+        acc_x = jnp.where(accepted, tenancy_lib.EWMA_ONE, 0)
+        new_acc = tenancy_lib.ewma_q16(tn.acc_ewma[tid], acc_x, a)
+        slow_x = tenancy_lib.ratio_q16(t_e - orig_tr, orig_tdu)
+        new_slow = tenancy_lib.ewma_q16(tn.slow_ewma[tid], slow_x, a)
+        new_occ = tenancy_lib.ewma_q16(tn.occ_ewma, occ_q, a)
         state = state._replace(tenants=tn._replace(
             used=tn.used.at[tid].add(
                 jnp.where(ok_upd & accepted, demand,

@@ -30,6 +30,7 @@ from repro.core.types import (
     T_INF,
     policy_score,
 )
+from repro.tenancy.table import ratio_q16_exact
 
 _WORD = 64
 
@@ -692,11 +693,12 @@ class TenantOracle(BackfillOracle):
 
     Wraps :class:`BackfillOracle` with the same
     :class:`repro.tenancy.HostTenantAccounts` arithmetic the device
-    tenancy gate uses (identical f32 operation order, so the mirrored
-    counters are bit-exact): the quota gate runs *after* queue work and
-    *before* the placement search, the parked-queue sweeps order by the
-    weighted fair-share key instead of FCFS, and ``reap`` deletes
-    overdue completions past ``t_e + grace`` charging the owner.
+    tenancy gate uses (identical f32 operation order and fixed-point
+    EWMAs, so the mirrored counters are bit-exact): the quota gate
+    runs *after* queue work and *before* the placement search, the
+    parked-queue sweeps order by the weighted fair-share key instead
+    of FCFS, and ``reap`` deletes overdue completions past
+    ``t_e + grace`` charging the owner.
     """
 
     def __init__(self, n_pe: int, policy: Policy, mode, spec,
@@ -739,17 +741,17 @@ class TenantOracle(BackfillOracle):
             self._retry_parked(t_now)
         self.retry_flag = False
         # occupancy sampled post-queue-work, like the device occ_ewma
-        occ_frac = (np.float32(popcount(self.sched._busy_row_at(t_now)))
-                    / np.float32(self.n_pe))
+        occ_q = ratio_q16_exact(
+            int(popcount(self.sched._busy_row_at(t_now))), self.n_pe)
         tid = self.accounts.clip_tid(self._tenant_of(req))
         if not self.accounts.allowed(tid, req.n_pe, req.t_du):
             self.accounts.record(tid, accepted=False, blocked=True,
-                                 parked=False, occ_frac=occ_frac)
+                                 parked=False, occ_q=occ_q)
             return False, -1, False
         accepted, t_s, parked = super().admit(req)
         self.accounts.record(
             tid, accepted=accepted, blocked=False, parked=parked,
-            occ_frac=occ_frac,
+            occ_q=occ_q,
             t_e=(t_s + req.t_du) if accepted else -1,
             t_r=req.t_r, t_du=req.t_du, n_pe=req.n_pe)
         return accepted, t_s, parked

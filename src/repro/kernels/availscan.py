@@ -36,12 +36,13 @@ leaves the kernel — the per-candidate ``[P]`` vectors (and the
 
 VMEM budget per program (defaults Pt=128, S<=1024, n_pe<=2048):
 occ_bits f32[S, pe] = 8 MiB worst case + tiles ~1.5 MiB < 16 MiB.
-The ops.py wrapper falls back to the pure-jnp path beyond these bounds.
+Beyond these bounds ops.py runs the pure-jnp path instead
+(``ops.fits``), and sessions report it as their ``search_path``.
 """
 from __future__ import annotations
 
 import functools
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -55,6 +56,21 @@ DEFAULT_PT = 128
 # TPU lane width; S and n_pe are padded to multiples of this.
 _LANE = 128
 _BIG = jnp.iinfo(jnp.int32).max
+
+
+def _interpret_mode() -> bool:
+    """``interpret=None`` resolved by platform: the kernels compile on a
+    TPU and run in the Pallas interpreter on the CPU backend (tests).
+    Any other backend has no kernel for them, which is an error rather
+    than a silent interpreter run."""
+    platform = jax.default_backend()
+    if platform == "tpu":
+        return False
+    if platform == "cpu":
+        return True
+    raise NotImplementedError(
+        f"the availscan kernels are written for TPU; there is no "
+        f"compiled kernel for platform {platform!r}")
 
 
 def _tile_rects(a, b, times, nxt, occ):
@@ -153,7 +169,7 @@ def availscan(
     #                        T_INF padding, not summary-pruned)
     *,
     pt: int = DEFAULT_PT,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Tiled scan over candidates, skipping all-dead tiles.
 
@@ -195,7 +211,7 @@ def availscan(
             jax.ShapeDtypeStruct((P_pad, 1), jnp.int32),
             jax.ShapeDtypeStruct((P_pad, 1), jnp.int32),
         ],
-        interpret=interpret,
+        interpret=_interpret_mode() if interpret is None else interpret,
     )(tlive, a_p, b_p,
       times[None, :], nxt[None, :], occ_bits)
     return nfree[:P, 0], tb[:P, 0], te[:P, 0]
@@ -235,7 +251,7 @@ def availscan_mr(
     live: jax.Array,       # bool/i32[P]: candidate is live
     *,
     pt: int = DEFAULT_PT,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Multi-resource :func:`availscan`: same tile-skip scan, but the
     free counts come back per plane (``n_free_planes[P, 128]``, column
@@ -275,7 +291,7 @@ def availscan_mr(
             jax.ShapeDtypeStruct((P_pad, 1), jnp.int32),
             jax.ShapeDtypeStruct((P_pad, 1), jnp.int32),
         ],
-        interpret=interpret,
+        interpret=_interpret_mode() if interpret is None else interpret,
     )(tlive, a_p, b_p,
       times[None, :], nxt[None, :], occ_bits, psel)
     return nfp[:P, :], tb[:P, 0], te[:P, 0]
@@ -381,7 +397,7 @@ def availscan_select(
     live: jax.Array,       # bool[P] live (unpruned) candidate mask
     *,
     pt: int = DEFAULT_PT,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Fused availscan + policy selection (one int32[8] result row).
 
@@ -420,7 +436,7 @@ def availscan_select(
             out_specs=pl.BlockSpec((1, 8), lambda i, s, t: (0, 0)),
         ),
         out_shape=jax.ShapeDtypeStruct((1, 8), jnp.int32),
-        interpret=interpret,
+        interpret=_interpret_mode() if interpret is None else interpret,
     )(scalars.astype(jnp.int32), tlive, starts_p, a_p, b_p,
       times[None, :], nxt[None, :], occ_bits)
     return acc[0]
@@ -505,7 +521,7 @@ def availscan_select_mr(
     *,
     pt: int = DEFAULT_PT,
     n_res: int = 1,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Multi-resource :func:`availscan_select` (DESIGN.md §11).
 
@@ -546,7 +562,7 @@ def availscan_select_mr(
             out_specs=pl.BlockSpec((1, 8), lambda i, s, t: (0, 0)),
         ),
         out_shape=jax.ShapeDtypeStruct((1, 8), jnp.int32),
-        interpret=interpret,
+        interpret=_interpret_mode() if interpret is None else interpret,
     )(scalars.astype(jnp.int32), tlive, starts_p, a_p, b_p,
       times[None, :], nxt[None, :], occ_bits, psel)
     return acc[0]

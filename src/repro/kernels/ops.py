@@ -17,9 +17,10 @@ produces, keeping the two paths element-identical.
 kernel (the per-candidate vectors never leave the kernel); the
 ``search`` hot path uses it on the kernel path.
 
-On shapes beyond the kernel's single-block VMEM budget the wrappers
-transparently fall back to the reference path (``search_select``
-returns ``None`` and the caller runs the jnp chain).
+On shapes beyond the kernel's single-block VMEM budget (:func:`fits`)
+the wrappers run the reference path instead (``search_select`` returns
+``None`` and the caller runs the jnp chain); sessions report which path
+their shape takes as the ``search_path`` metric.
 """
 from __future__ import annotations
 
@@ -39,23 +40,33 @@ from repro.kernels import availscan as _k
 _MAX_OCC_ELEMS = 2 * 1024 * 1024
 
 
-def _interpret_mode() -> bool:
-    # Real TPU executes the compiled kernel; anywhere else (this
-    # container is CPU-only) runs the kernel body in interpret mode.
-    return jax.default_backend() != "tpu"
-
-
 def _round_up(x: int, m: int) -> int:
     return -(-x // m) * m
 
 
+def fits(capacity: int, n_pe: int, rspec=None) -> bool:
+    """Whether a timeline of this shape is within the kernels' budget.
+
+    Beyond it the wrappers below run the jnp reference instead; the
+    choice is static (shape only), so the service reports it as the
+    session's ``search_path`` metric.
+    """
+    if rspec is not None:
+        if rspec.R > _k._LANE:
+            return False
+        n_pe = rspec.total_bits
+    return (_round_up(max(capacity, _k._LANE), _k._LANE)
+            * _round_up(max(n_pe, _k._LANE), _k._LANE)
+            <= _MAX_OCC_ELEMS)
+
+
 def _padded_operands(tl: Timeline, n_pe: int):
     """Lane-padded dense operands shared by both kernel entries."""
+    if not fits(tl.capacity, n_pe):
+        return None
     S = tl.capacity
     S_pad = _round_up(max(S, _k._LANE), _k._LANE)
     n_pe_pad = _round_up(max(n_pe, _k._LANE), _k._LANE)
-    if S_pad * n_pe_pad > _MAX_OCC_ELEMS:
-        return None
     occ_bits = tl_lib.unpack_bits(tl.occ, n_pe).astype(jnp.float32)
     occ_bits = jnp.pad(
         occ_bits, ((0, S_pad - S), (0, n_pe_pad - n_pe)))
@@ -72,14 +83,12 @@ def _padded_operands_mr(tl: Timeline, rspec,
     bit is a valid unit of plane ``r``) both excludes padding/masked
     units from the free counts and routes each plane to its own output
     lane — so no pad correction exists on this path."""
-    if rspec.R > _k._LANE:
+    if not fits(tl.capacity, 0, rspec):
         return None
     S = tl.capacity
     n_bits = rspec.total_bits
     S_pad = _round_up(max(S, _k._LANE), _k._LANE)
     n_bits_pad = _round_up(max(n_bits, _k._LANE), _k._LANE)
-    if S_pad * n_bits_pad > _MAX_OCC_ELEMS:
-        return None
     occ_bits = tl_lib.unpack_bits(tl.occ, n_bits).astype(jnp.float32)
     occ_bits = jnp.pad(
         occ_bits, ((0, S_pad - S), (0, n_bits_pad - n_bits)))
@@ -117,8 +126,7 @@ def availability_rectangles(
         a = jnp.minimum(starts, T_INF - t_du)
         b = a + t_du
         nfp_raw, tb_raw, te_raw = _k.availscan_mr(
-            occ_bits, psel, times, nxt, a, b, valid,
-            interpret=_interpret_mode())
+            occ_bits, psel, times, nxt, a, b, valid)
         zero = jnp.int32(0)
         t_begin = jnp.minimum(jnp.maximum(tb_raw, t_now), a)
         return search_lib.Rectangles(
@@ -140,8 +148,7 @@ def availability_rectangles(
     b = a + t_du
 
     nfree_raw, tb_raw, te_raw = _k.availscan(
-        occ_bits, times, nxt, a, b, valid,
-        interpret=_interpret_mode())
+        occ_bits, times, nxt, a, b, valid)
 
     zero = jnp.int32(0)
     n_free = nfree_raw - (n_pe_pad - n_pe)   # padded PE bits never busy
@@ -191,7 +198,7 @@ def search_select(
             jnp.asarray(demand_tail, jnp.int32)])
         acc = _k.availscan_select_mr(
             occ_bits, psel, times, nxt, starts, a, b, scalars, live,
-            n_res=rspec.R, interpret=_interpret_mode())
+            n_res=rspec.R)
         return dict(found=acc[7] > 0, best=acc[3], n_free=acc[4],
                     t_begin=acc[5], t_end=acc[6])
     ops = _padded_operands(tl, n_pe)
@@ -206,7 +213,6 @@ def search_select(
         jnp.asarray(n_req, jnp.int32), jnp.asarray(t_now, jnp.int32),
         jnp.int32(n_pe_pad - n_pe)])
     acc = _k.availscan_select(
-        occ_bits, times, nxt, starts, a, b, scalars, live,
-        interpret=_interpret_mode())
+        occ_bits, times, nxt, starts, a, b, scalars, live)
     return dict(found=acc[7] > 0, best=acc[3], n_free=acc[4],
                 t_begin=acc[5], t_end=acc[6])

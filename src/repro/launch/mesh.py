@@ -18,17 +18,12 @@ from __future__ import annotations
 from typing import Optional, Union
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 
 def _make_mesh(shape, axes) -> Mesh:
-    # jax.sharding.AxisType post-dates the pinned jax; pass it when
-    # present (explicit Auto matches the default), else omit it.
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(
-            shape, axes, axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(
+        shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -39,7 +34,7 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
 
 
 def make_host_mesh() -> Mesh:
-    """1x1 mesh on the single real CPU device (tests, smoke runs)."""
+    """1x1 mesh on the first local device (one chip, or the CPU)."""
     return _make_mesh((1, 1), ("data", "model"))
 
 

@@ -18,10 +18,12 @@ request to a *tenant* and enforces per-tenant policy at admission:
     policy and backfill ids of the fused admit step.
 ``HostTenantAccounts``
     The numpy mirror used by the differential ``TenantOracle`` and
-    the host-routed partition gate.  All fractional accounting is
-    float32 on both sides with identical expression shapes, so the
-    device table and the host mirror agree **bit-for-bit** (the same
-    contract the PR 4 backfill oracle established for decisions).
+    the host-routed partition gate.  Usage accounting is float32 on
+    both sides with identical expression shapes; the telemetry EWMAs
+    are int32 fixed point (:data:`EWMA_ONE`), whose integer rounding
+    is the same on every backend.  So the device table and the host
+    mirror agree **bit-for-bit** (the same contract the PR 4 backfill
+    oracle established for decisions).
 
 The table hangs off ``SchedulerState.tenants`` as an *optional*
 trailing field: ``None`` contributes no pytree leaves, so zero-tenant
@@ -40,6 +42,13 @@ import jax.numpy as jnp
 
 #: int32 "+infinity" for unlimited concurrent-reservation caps.
 _I32_MAX = 2**31 - 1
+
+#: Fixed-point scale of the telemetry EWMAs: int32 in units of
+#: ``1 / EWMA_ONE``.  Float EWMAs cannot match across backends (XLA may
+#: or may not fuse ``e*(1-a) + x*a`` into an FMA, and a TPU divides
+#: with a reciprocal); integer arithmetic rounds the same everywhere.
+EWMA_BITS = 16
+EWMA_ONE = 1 << EWMA_BITS
 
 #: Supported over-quota dispositions.  ``"park"`` (defer instead of
 #: reject) is reserved for a later PR: parking an over-quota request
@@ -71,7 +80,7 @@ class TenantSpec:
         reaping.
     ``ewma_alpha``
         coefficient of the telemetry EWMAs (acceptance, slowdown,
-        occupancy).
+        occupancy), applied in steps of ``1 / EWMA_ONE``.
     """
 
     weights: Tuple[float, ...] = (1.0,)
@@ -139,6 +148,10 @@ class TenantSpec:
             [_I32_MAX if m is None else int(m) for m in self.max_live],
             np.int32)
 
+    def alpha_q16(self) -> int:
+        """``ewma_alpha`` in EWMA fixed point, in ``[1, EWMA_ONE]``."""
+        return max(1, round(self.ewma_alpha * EWMA_ONE))
+
     def padded(self, n_tenants: int) -> "TenantSpec":
         """This spec widened to ``n_tenants`` with neutral tenants.
 
@@ -185,10 +198,11 @@ class TenantTable(NamedTuple):
     n_quota_rejected: jax.Array  # int32[T] rejected by the quota gate
     n_parked: jax.Array      # int32[T] accepted into the deferral queue
     n_reaped: jax.Array      # int32[T] reservations reaped overdue
-    acc_ewma: jax.Array      # float32[T] per-tenant acceptance EWMA
-    slow_ewma: jax.Array     # float32[T] per-tenant slowdown EWMA
-    occ_ewma: jax.Array      # float32 scalar machine-occupancy EWMA
-    alpha: jax.Array         # float32 scalar EWMA coefficient (traced)
+    # the EWMAs and alpha are int32 fixed point (units 1/EWMA_ONE)
+    acc_ewma: jax.Array      # int32[T] per-tenant acceptance EWMA
+    slow_ewma: jax.Array     # int32[T] per-tenant slowdown EWMA
+    occ_ewma: jax.Array      # int32 scalar machine-occupancy EWMA
+    alpha: jax.Array         # int32 scalar EWMA coefficient (traced)
     pend_tenant: jax.Array   # int32[K] pending-slot owner; -1 = free
     park_tenant: jax.Array   # int32[Q] queue-slot owner; -1 = free
     park_ta: jax.Array       # int32[Q] queue-slot arrival time
@@ -213,9 +227,9 @@ def init_table(spec: TenantSpec, pending_capacity: int,
         used=zf(), live=zi(),
         n_accepted=zi(), n_rejected=zi(), n_quota_rejected=zi(),
         n_parked=zi(), n_reaped=zi(),
-        acc_ewma=zf(), slow_ewma=zf(),
-        occ_ewma=jnp.float32(0.0),
-        alpha=jnp.float32(spec.ewma_alpha),
+        acc_ewma=zi(), slow_ewma=zi(),
+        occ_ewma=jnp.int32(0),
+        alpha=jnp.int32(spec.alpha_q16()),
         pend_tenant=jnp.full((pending_capacity,), -1, jnp.int32),
         park_tenant=jnp.full((park_capacity,), -1, jnp.int32),
         park_ta=jnp.zeros((park_capacity,), jnp.int32),
@@ -271,27 +285,61 @@ def fair_key(table: TenantTable, t_now: jax.Array) -> jax.Array:
     return jnp.take(table.weight, tid) * wait
 
 
-def _ewma(e: np.float32, x: np.float32, a: np.float32) -> np.float32:
-    """One float32 EWMA step, matching XLA's compilation bit-for-bit.
+def ratio_q16_exact(num: int, den: int) -> int:
+    """``num / den`` in EWMA fixed point: ``floor(num * EWMA_ONE /
+    den)`` for ``num >= 0, den > 0``, saturated to the int32 range.
+    The definition :func:`ratio_q16` computes on the device."""
+    return min((max(num, 0) << EWMA_BITS) // max(den, 1), _I32_MAX)
 
-    XLA contracts ``e*(1-a) + x*a`` into fused multiply-adds: both
-    float32 products stay exact and only the final sum rounds.  A
-    float64 evaluation reproduces that (f32 products are exact in f64)
-    where the naive two-rounding numpy expression drifts by ULPs.
-    """
-    one = np.float32(1.0)
-    return np.float32(np.float64(e) * np.float64(one - a)
-                      + np.float64(x) * np.float64(a))
+
+def ewma_q16_exact(e: int, x: int, a: int) -> int:
+    """One fixed-point EWMA step ``e + floor((x - e) * a / EWMA_ONE)``;
+    the result lies between ``e`` and ``x``.  The definition
+    :func:`ewma_q16` computes on the device."""
+    return e + (((x - e) * a) >> EWMA_BITS)
+
+
+def ratio_q16(num: jax.Array, den: jax.Array) -> jax.Array:
+    """:func:`ratio_q16_exact` in int32 arithmetic: integer quotient,
+    then the fraction's bits by long division on the remainder (kept
+    below ``den`` in uint32, so doubling it never overflows)."""
+    num = jnp.maximum(jnp.asarray(num, jnp.int32), 0)
+    den = jnp.maximum(jnp.asarray(den, jnp.int32), 1)
+    q = num // den
+    r = (num - q * den).astype(jnp.uint32)
+    d = den.astype(jnp.uint32)
+    frac = jnp.uint32(0)
+    for _ in range(EWMA_BITS):
+        r = r << 1
+        bit = r >= d
+        r = jnp.where(bit, r - d, r)
+        frac = (frac << 1) | bit.astype(jnp.uint32)
+    # q * EWMA_ONE overflows int32 exactly when q >= 2**(31 - BITS)
+    return jnp.where(q >= (1 << (31 - EWMA_BITS)), jnp.int32(_I32_MAX),
+                     (q << EWMA_BITS) | frac.astype(jnp.int32))
+
+
+def ewma_q16(e: jax.Array, x: jax.Array, a: jax.Array) -> jax.Array:
+    """:func:`ewma_q16_exact` in int32 arithmetic.  ``d * a`` can
+    exceed int32, so ``d`` splits into its high part (``d >> BITS``,
+    a floor) and low bits: ``floor(d*a / ONE) = hi*a + floor(lo*a /
+    ONE)``, with ``lo*a < 2**32`` in uint32.  The result lies between
+    ``e`` and ``x``, so int32 wrap-around in between cancels out."""
+    d = x - e
+    hi = d >> EWMA_BITS
+    lo = (d & (EWMA_ONE - 1)).astype(jnp.uint32)
+    low = (lo * a.astype(jnp.uint32)) >> EWMA_BITS
+    return e + hi * a + low.astype(jnp.int32)
 
 
 class HostTenantAccounts:
     """Numpy mirror of :class:`TenantTable` accounting (bit-exact).
 
     Shared by the differential :class:`~repro.core.hostsched.
-    TenantOracle` and the host-routed partition quota gate.  Every
-    fractional update reproduces the device expression shape in
-    float32, so ``snapshot()`` matches the device table bit-for-bit
-    after identical request streams.
+    TenantOracle` and the host-routed partition quota gate.  ``used``
+    reproduces the device expression shape in float32 and the EWMAs
+    follow the same fixed-point definitions, so the fields match the
+    device table bit-for-bit after identical request streams.
     """
 
     def __init__(self, spec: TenantSpec):
@@ -307,10 +355,10 @@ class HostTenantAccounts:
         self.n_quota_rejected = np.zeros(T, np.int32)
         self.n_parked = np.zeros(T, np.int32)
         self.n_reaped = np.zeros(T, np.int32)
-        self.acc_ewma = np.zeros(T, np.float32)
-        self.slow_ewma = np.zeros(T, np.float32)
-        self.occ_ewma = np.float32(0.0)
-        self.alpha = np.float32(spec.ewma_alpha)
+        self.acc_ewma = np.zeros(T, np.int32)
+        self.slow_ewma = np.zeros(T, np.int32)
+        self.occ_ewma = np.int32(0)
+        self.alpha = spec.alpha_q16()
 
     @property
     def n_tenants(self) -> int:
@@ -327,11 +375,14 @@ class HostTenantAccounts:
             and (self.live[tid] < self.max_live[tid]))
 
     def record(self, tid: int, *, accepted: bool, blocked: bool,
-               parked: bool, occ_frac: np.float32,
+               parked: bool, occ_q: int = 0,
                t_e: int = -1, t_r: int = 0, t_du: int = 1,
                n_pe: int = 0) -> None:
-        """One real request's accounting (mirrors ``_admit_impl``)."""
-        one = np.float32(1.0)
+        """One real request's accounting (mirrors ``_admit_impl``).
+
+        ``occ_q`` is the machine occupancy at arrival in EWMA fixed
+        point (:func:`ratio_q16_exact` of busy PEs over the machine).
+        """
         a = self.alpha
         if accepted:
             self.used[tid] = np.float32(
@@ -341,15 +392,17 @@ class HostTenantAccounts:
             self.n_accepted[tid] += 1
             if parked:
                 self.n_parked[tid] += 1
-            slow = np.float32(t_e - t_r) / np.float32(t_du)
-            self.slow_ewma[tid] = _ewma(self.slow_ewma[tid], slow, a)
+            slow = ratio_q16_exact(t_e - t_r, t_du)
+            self.slow_ewma[tid] = ewma_q16_exact(
+                int(self.slow_ewma[tid]), slow, a)
         else:
             self.n_rejected[tid] += 1
             if blocked:
                 self.n_quota_rejected[tid] += 1
-        x = one if accepted else np.float32(0.0)
-        self.acc_ewma[tid] = _ewma(self.acc_ewma[tid], x, a)
-        self.occ_ewma = _ewma(self.occ_ewma, np.float32(occ_frac), a)
+        x = EWMA_ONE if accepted else 0
+        self.acc_ewma[tid] = ewma_q16_exact(int(self.acc_ewma[tid]), x, a)
+        self.occ_ewma = np.int32(
+            ewma_q16_exact(int(self.occ_ewma), occ_q, a))
 
     def release(self, tenant: int) -> None:
         if tenant >= 0:
@@ -372,6 +425,6 @@ class HostTenantAccounts:
             n_quota_rejected=self.n_quota_rejected.copy(),
             n_parked=self.n_parked.copy(),
             n_reaped=self.n_reaped.copy(),
-            acc_ewma=self.acc_ewma.copy(),
-            slow_ewma=self.slow_ewma.copy(),
-            occ_ewma=np.float32(self.occ_ewma))
+            acc_ewma=self.acc_ewma / EWMA_ONE,
+            slow_ewma=self.slow_ewma / EWMA_ONE,
+            occ_ewma=self.occ_ewma / EWMA_ONE)

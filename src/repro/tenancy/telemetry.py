@@ -14,7 +14,7 @@ from typing import Dict
 
 import numpy as np
 
-from .table import TenantTable
+from .table import EWMA_ONE, TenantTable
 
 #: Table fields surfaced per tenant by :func:`tenant_view`.
 _PER_TENANT = ("weight", "quota", "max_live", "used", "live",
@@ -32,10 +32,17 @@ def snapshot(table: TenantTable, fetch=None) -> Dict[str, np.ndarray]:
     if fetch is None:
         import jax
         fetch = jax.device_get
-    host = fetch({f: getattr(table, f) for f in _PER_TENANT
-                  + ("occ_ewma",)})
-    out = {k: np.asarray(v) for k, v in host.items()}
-    out["occ_ewma"] = np.float32(out["occ_ewma"])
+    return to_host(fetch({f: getattr(table, f) for f in _PER_TENANT
+                          + ("occ_ewma",)}))
+
+
+def to_host(fetched: Dict) -> Dict[str, np.ndarray]:
+    """Fetched table fields as numpy, the fixed-point EWMAs as
+    fractions (``/ EWMA_ONE``; exact in float64)."""
+    out = {k: np.asarray(v) for k, v in fetched.items()}
+    for k in ("acc_ewma", "slow_ewma", "occ_ewma"):
+        if k in out:
+            out[k] = out[k] / EWMA_ONE
     return out
 
 

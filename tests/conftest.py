@@ -1,5 +1,7 @@
-"""Shared fixtures.  NOTE: no XLA_FLAGS here — tests run on the single
-real CPU device; only launch/dryrun.py forces 512 placeholder devices.
+"""Shared fixtures.  Tests run on the CPU backend (``JAX_PLATFORMS=cpu``,
+Pallas kernels in interpret mode).  No XLA_FLAGS here: only
+launch/dryrun.py forces 512 placeholder devices, and the chip is driven
+by ``chip_smoke.py``, never by the test suite.
 """
 import random
 

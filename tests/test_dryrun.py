@@ -73,12 +73,15 @@ def test_serve_memory_fits_everywhere():
 
 
 @pytest.mark.slow
-def test_one_cell_compiles_in_subprocess():
+def test_one_cell_compiles_in_subprocess(tmp_path):
+    # the child compiles on the CPU backend: it must never try to take
+    # an accelerator the test process (or another worker) may hold
     out = subprocess.run(
         [sys.executable, "-m", "repro.launch.dryrun",
          "--arch", "stablelm-1.6b", "--shape", "decode_32k",
-         "--mesh", "single", "--out", "/tmp/dryrun_test", "--force"],
+         "--mesh", "single", "--out", str(tmp_path), "--force"],
         capture_output=True, text=True, timeout=540,
-        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin"},
+        env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
+             "JAX_PLATFORMS": "cpu"},
         cwd=Path(__file__).resolve().parent.parent)
     assert "1 ok, 0 skipped, 0 failed" in out.stdout, out.stdout[-2000:]

@@ -122,3 +122,29 @@ def test_full_find_allocation_with_kernel():
                 (rb.t_s, rb.pe_ids, rb.rectangle)
             a.add_allocation(ra.t_s, ra.t_e, list(ra.pe_ids))
             b.add_allocation(ra.t_s, ra.t_e, list(ra.pe_ids))
+
+
+@pytest.mark.parametrize("cfg, path", [
+    (dict(n_pe=64, use_kernel=True), "kernel"),
+    (dict(n_pe=64), "jnp"),
+    # 4096 records x 1024 PEs is past the kernel's single-block budget
+    (dict(n_pe=1024, capacity=4096, use_kernel=True), "jnp"),
+    (dict(n_pe=64, lanes=2, use_kernel=True), "kernel"),
+    (dict(n_pe=64, n_partitions=2, use_kernel=True), "kernel"),
+], ids=["kernel", "jnp", "over-budget", "lanes", "partitions"])
+def test_session_reports_search_path(cfg, path):
+    from repro.api import ReservationService, ServiceConfig
+    sess = ReservationService(ServiceConfig(**cfg)).session()
+    assert sess.metrics()["search_path"] == path
+
+
+@pytest.mark.parametrize("platform, interpret", [
+    ("cpu", True), ("tpu", False), ("gpu", None)])
+def test_interpret_mode_by_platform(platform, interpret, monkeypatch):
+    from repro.kernels import availscan as _k
+    monkeypatch.setattr(jax, "default_backend", lambda: platform)
+    if interpret is None:
+        with pytest.raises(NotImplementedError, match="gpu"):
+            _k._interpret_mode()
+    else:
+        assert _k._interpret_mode() is interpret

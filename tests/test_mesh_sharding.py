@@ -129,7 +129,8 @@ def test_lane_spec_and_shard_ensemble():
     sharded = shard_rules.shard_ensemble(mesh, states)
     # lane axis sharded over data, payload axes replicated
     sh = sharded.tl.times.sharding
-    assert sh.spec[0] in (("data",), ("pod", "data"), None)
+    # a one-axis entry may be spelled "data" or ("data",): same meaning
+    assert sh.spec[0] in ("data", ("data",), ("pod", "data"), None)
     assert all(ax is None for ax in sh.spec[1:])
     np.testing.assert_array_equal(np.asarray(sharded.tl.times),
                                   np.asarray(states.tl.times))

@@ -16,7 +16,8 @@ device-resident accumulators.  The gates here:
 * **host oracle**: :class:`repro.core.hostsched.TenantOracle` matches
   the device path bit-for-bit on quota rejections, fair-share
   promotion order, reaping, and every per-tenant counter including
-  the float32 EWMAs;
+  the fixed-point EWMAs (whose int32 device arithmetic equals their
+  exact integer definitions on any backend);
 * **poll-cheap telemetry**: an idle ``Session.metrics()`` performs
   zero device fetches (satellite: the ``_device_fetch`` choke point).
 """
@@ -179,6 +180,39 @@ def test_device_matches_tenant_oracle_bit_for_bit():
                     err_msg=f"{mode}/{policy}/{f}")
             assert np.asarray(t.occ_ewma) == a.occ_ewma
             assert int(np.asarray(t.n_quota_rejected).sum()) > 0
+
+
+@pytest.mark.parametrize("kind", ["ratio", "ewma"])
+def test_fixed_point_ewma_matches_exact_definition(kind):
+    """The int32 device arithmetic of the telemetry EWMAs equals the
+    exact (Python int) definitions, overflow edges included."""
+    import jax.numpy as jnp
+
+    from repro.tenancy import table as tn_lib
+    rng = np.random.default_rng(11)
+    top = 2**31 - 1
+    if kind == "ratio":
+        cases = [(0, 1), (5, 1), (1, 3), (top, 1), (top, top),
+                 (2**15, 1), (2**15 - 1, 1), (7, 2**30 + 3),
+                 (123456789, 10800), (-4, 3)]
+        cases += list(zip(rng.integers(0, top, 500).tolist(),
+                          rng.integers(1, top, 500).tolist()))
+        cases += list(zip(rng.integers(0, 2**20, 500).tolist(),
+                          rng.integers(1, 2**16, 500).tolist()))
+        cols = [jnp.asarray(c, jnp.int32) for c in zip(*cases)]
+        got = np.asarray(tn_lib.ratio_q16(*cols))
+        want = [tn_lib.ratio_q16_exact(*c) for c in cases]
+    else:
+        one = tn_lib.EWMA_ONE
+        cases = [(0, top, one), (top, 0, one), (top, 0, 1), (0, top, 1),
+                 (one, 0, 3277), (0, one, 3277), (5, 5, 100)]
+        cases += list(zip(rng.integers(0, top, 1000).tolist(),
+                          rng.integers(0, top, 1000).tolist(),
+                          rng.integers(1, one + 1, 1000).tolist()))
+        cols = [jnp.asarray(c, jnp.int32) for c in zip(*cases)]
+        got = np.asarray(tn_lib.ewma_q16(*cols))
+        want = [tn_lib.ewma_q16_exact(*c) for c in cases]
+    np.testing.assert_array_equal(got, np.asarray(want, np.int64))
 
 
 def test_fair_share_changes_promotion_order_and_matches_oracle():
