@@ -3,7 +3,6 @@
     PYTHONPATH=src python -m benchmarks.run [--full] [--only NAME]
     PYTHONPATH=src python -m benchmarks.run --list
     PYTHONPATH=src python -m benchmarks.run --check [--tolerance T]
-    PYTHONPATH=src python -m benchmarks.run --profile [--profile-dir D]
 
 Prints CSV blocks: ``name,...columns`` per section.  ``--full`` uses
 the paper's 10^4-job workloads (slow); default is a reduced size that
@@ -26,10 +25,6 @@ early-reject speedup, BENCH_index.json).  Ratios only:
 absolute wall times are meaningless on shared runners, but a device
 path that regresses from 3x-faster-than-host to slower-than-host
 moves its ratio far beyond any plausible machine noise.
-
-``--profile`` writes a ``jax.profiler`` trace (one warmed
-``admit_stream`` + one vmapped sweep-grid dispatch) to
-``--profile-dir`` for the CI artifact upload.
 """
 from __future__ import annotations
 
@@ -251,40 +246,6 @@ def check(tolerance: float) -> int:
     return len(failures)
 
 
-def profile(outdir: str) -> None:
-    """Capture a ``jax.profiler`` trace of the two hot dispatch paths.
-
-    One warmed ``admit_stream`` scan (the standard admission workload,
-    index on) and one warmed vmapped sweep-grid dispatch — both run
-    once outside the trace so compilation and the grow-once overflow
-    protocol settle, then once inside it.  The trace directory is the
-    CI ``perf-profile`` artifact; open it with any Perfetto/
-    TensorBoard trace viewer.
-    """
-    import jax
-
-    from repro.core.types import ALL_POLICIES, Policy
-    from repro.sim import (GridSpec, WorkloadParams, generate,
-                           simulate_batched, simulate_grid)
-
-    jobs = [j for j in generate(WorkloadParams(
-        n_jobs=240, n_pe=64, seed=0,
-        u_low=2.0, u_med=4.0, u_hi=6.0)) if j.n_pe <= 64]
-    spec = GridSpec(
-        policies=ALL_POLICIES, arrival_factors=(1.0,), seeds=(0,),
-        flex_factors=(3.0,),
-        base=WorkloadParams(u_low=2.0, u_med=4.0, u_hi=6.0),
-        n_pe=64, n_jobs=120)
-    # warm: compile + grow to steady-state shapes
-    simulate_batched(jobs, 64, Policy.PE_W, capacity=32, index_tile=16)
-    simulate_grid(spec, capacity=32)
-    with jax.profiler.trace(outdir):
-        simulate_batched(jobs, 64, Policy.PE_W, capacity=32,
-                         index_tile=16)
-        simulate_grid(spec, capacity=32)
-    print(f"# profiler trace written to {outdir}")
-
-
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--full", action="store_true",
@@ -296,19 +257,11 @@ def main() -> None:
                     help="ratio-gate regression mode vs BENCH_*.json")
     ap.add_argument("--tolerance", type=float, default=0.5,
                     help="allowed relative ratio drift in --check")
-    ap.add_argument("--profile", action="store_true",
-                    help="write a jax.profiler trace of one warmed "
-                         "admit_stream + sweep-grid dispatch")
-    ap.add_argument("--profile-dir", default="artifacts/profile",
-                    help="trace output directory for --profile")
     args = ap.parse_args()
     from repro.launch.compile_cache import use_compile_cache
     use_compile_cache(_ROOT / ".jax_cache")
     if args.check:
         sys.exit(1 if check(args.tolerance) else 0)
-    if args.profile:
-        profile(args.profile_dir)
-        return
     n_jobs = 10_000 if args.full else 2_000
     t0 = time.time()
 

@@ -198,6 +198,17 @@ def _check_demands(rspec, reqs) -> None:
 
 
 
+def _work_counters(s) -> Dict[str, jax.Array]:
+    """The admit searches' index and kernel counters of a state, summed
+    over its lanes: ``early_rejects`` (real requests the index rejected
+    whole), ``search_tiles`` (candidate tiles the kernel covered) and
+    ``search_tiles_skipped`` (those without a live candidate)."""
+    tiles = jnp.sum(s.n_search_tiles)
+    return dict(early_rejects=jnp.sum(s.n_early_rejects),
+                search_tiles=tiles,
+                search_tiles_skipped=tiles - jnp.sum(s.n_search_tiles_run))
+
+
 def _concat_tree(chunks: List[Any], axis: int):
     """Concatenate a list of equally-structured pytrees."""
     if len(chunks) == 1:
@@ -358,7 +369,9 @@ class Session:
         Device sessions report ``search_path``: ``"kernel"`` when their
         searches run the Pallas kernel, ``"jnp"`` when they run the
         jnp reference (``use_kernel=False``, or a timeline beyond the
-        kernel's budget).
+        kernel's budget), and the admit searches' work counters
+        ``early_rejects``, ``search_tiles`` and ``search_tiles_skipped``
+        (DESIGN.md §13), which rewind with :meth:`restore`.
 
         On multi-tenant sessions the ``"tenants"`` key carries the
         per-tenant telemetry arrays (weights, quotas, usage, live
@@ -650,6 +663,10 @@ class _StreamBackend(_BackendBase):
         return self.engine.records()
 
     def offer(self, requests, *, policy, routing, flush) -> OfferResult:
+        with jax.profiler.TraceAnnotation("repro.offer"):
+            return self._offer(requests, policy, routing, flush)
+
+    def _offer(self, requests, policy, routing, flush) -> OfferResult:
         if routing is not None:
             raise ValueError("routing applies to partitioned sessions")
         if not flush and self.ring is None:
@@ -703,10 +720,12 @@ class _StreamBackend(_BackendBase):
         def drain_one():
             # keep the ring intact if the chunk raises (auto_grow=False
             # overflow): the popped requests stay staged for a retry
-            ring_snap = self.ring.snapshot()
-            batch, valid = self.ring.pop_chunk(chunk, self.cfg.n_pe)
+            with jax.profiler.TraceAnnotation("repro.offer.stage"):
+                ring_snap = self.ring.snapshot()
+                batch, valid = self.ring.pop_chunk(chunk, self.cfg.n_pe)
             try:
-                decs.append(self._admit_batch(batch, pid))
+                with jax.profiler.TraceAnnotation("repro.offer.dispatch"):
+                    decs.append(self._admit_batch(batch, pid))
             except Exception:
                 self.ring.restore(ring_snap)
                 raise
@@ -717,7 +736,8 @@ class _StreamBackend(_BackendBase):
         i = 0
         while i < len(reqs):
             take = min(self.ring.free, len(reqs) - i)
-            self.ring.push(reqs[i:i + take])
+            with jax.profiler.TraceAnnotation("repro.offer.stage"):
+                self.ring.push(reqs[i:i + take])
             i += take
             while self.ring.count >= chunk:
                 drain_one()
@@ -760,21 +780,24 @@ class _StreamBackend(_BackendBase):
         staged = None
 
         def stage():
-            popped = self.ring.pop_chunk(chunk, self.cfg.n_pe)
+            with jax.profiler.TraceAnnotation("repro.offer.stage"):
+                popped = self.ring.pop_chunk(chunk, self.cfg.n_pe)
             ltas.append(self.ring.last_popped_t_a)
             return popped
 
         def dispatch(cur) -> None:
             batch, valid = cur
-            state, dec = batch_lib.admit_stream_donated(
-                self._state, batch, jnp.int32(pid), self._bf,
-                n_pe=self.cfg.n_pe,
-                auto_release=self.cfg.auto_release,
-                use_kernel=self.cfg.use_kernel)
-            self._state = state
-            # jnp.any copies the latch into a fresh buffer: the next
-            # dispatch donates `state` (this leaf included) away
-            ovfs.append(jnp.any(state.overflow))
+            with jax.profiler.TraceAnnotation("repro.offer.dispatch"):
+                state, dec = batch_lib.admit_stream_donated(
+                    self._state, batch, jnp.int32(pid), self._bf,
+                    n_pe=self.cfg.n_pe,
+                    auto_release=self.cfg.auto_release,
+                    use_kernel=self.cfg.use_kernel)
+                self._state = state
+                # jnp.any copies the latch into a fresh buffer: the
+                # next dispatch donates `state` (this leaf included)
+                # away
+                ovfs.append(jnp.any(state.overflow))
             decs.append(dec)
             batches.append(batch)
             valids.append(valid)
@@ -792,7 +815,8 @@ class _StreamBackend(_BackendBase):
         i = 0
         while i < len(reqs):
             take = min(self.ring.free, len(reqs) - i)
-            self.ring.push(reqs[i:i + take])
+            with jax.profiler.TraceAnnotation("repro.offer.stage"):
+                self.ring.push(reqs[i:i + take])
             i += take
             drain(lambda: self.ring.count >= chunk)
         if flush:
@@ -821,52 +845,64 @@ class _StreamBackend(_BackendBase):
         """
         if not self._inflight:
             return
-        inflight, self._inflight = self._inflight, []
+        with jax.profiler.TraceAnnotation("repro.drain"):
+            self._drain(self._inflight)
+
+    def _drain(self, inflight: List[dict]) -> None:
+        self._inflight = []
         all_ovfs = [o for ctx in inflight for o in ctx["ovfs"]]
         # the drain's single synchronization point: all latches at once
-        latched = np.asarray(_device_fetch(jnp.stack(all_ovfs)))
+        with jax.profiler.TraceAnnotation("repro.drain.sync"):
+            latched = np.asarray(_device_fetch(jnp.stack(all_ovfs)))
         err = None
         if latched.any():
-            g = int(latched.argmax())     # first latched dispatch
-            c = 0                          # -> (offer c, its chunk g)
-            while g >= len(inflight[c]["ovfs"]):
-                g -= len(inflight[c]["ovfs"])
-                c += 1
-            for ci in range(c, len(inflight)):
-                ctx = inflight[ci]
-                err = self._replay_chunks(
-                    g if ci == c else 0, ctx, rollback=(ci == c))
-                if err is not None:
-                    # terminal overflow: every later dispatch was
-                    # state-preserving.  Restage undecided requests in
-                    # arrival order — newest offer pushed first so the
-                    # oldest tail ends up at the ring head.
-                    for later in reversed(inflight[ci + 1:]):
-                        self.counters["chunks"] -= len(
-                            later["batches"])
-                        self._restage_tail(0, later["batches"],
-                                           later["valids"],
-                                           later["ltas"])
-                        del later["decs"][:], later["batches"][:], \
-                            later["valids"][:]
-                    k = ctx["fail_k"]
-                    self._restage_tail(k, ctx["batches"],
-                                       ctx["valids"], ctx["ltas"])
-                    del ctx["decs"][k:], ctx["batches"][k:], \
-                        ctx["valids"][k:]
-                    break
-        for ctx in inflight:
-            res = ctx["result"]
-            res._finalize = None
-            if ctx["decs"]:
-                res._decision = _concat_tree(ctx["decs"], axis=0)
-                res._batch = _concat_tree(ctx["batches"], axis=0)
-                res._valid = np.concatenate(ctx["valids"])
-                self._defer_accepted(res._decision, res._valid)
-            else:
-                res._allocations = []
+            with jax.profiler.TraceAnnotation("repro.drain.replay"):
+                err = self._replay_from(int(latched.argmax()), inflight)
+        with jax.profiler.TraceAnnotation("repro.drain.concat"):
+            for ctx in inflight:
+                res = ctx["result"]
+                res._finalize = None
+                if ctx["decs"]:
+                    res._decision = _concat_tree(ctx["decs"], axis=0)
+                    res._batch = _concat_tree(ctx["batches"], axis=0)
+                    res._valid = np.concatenate(ctx["valids"])
+                    self._defer_accepted(res._decision, res._valid)
+                else:
+                    res._allocations = []
         if err is not None:
             raise err
+
+    def _replay_from(self, g: int, inflight: List[dict]
+                     ) -> Optional[Exception]:
+        """Grow and replay from dispatch ``g``, the first latched one
+        of the drained offers (see :meth:`_drain_inflight`); returns the
+        terminal overflow's error, if any, after restaging."""
+        c = 0                          # -> (offer c, its chunk g)
+        while g >= len(inflight[c]["ovfs"]):
+            g -= len(inflight[c]["ovfs"])
+            c += 1
+        for ci in range(c, len(inflight)):
+            ctx = inflight[ci]
+            err = self._replay_chunks(
+                g if ci == c else 0, ctx, rollback=(ci == c))
+            if err is not None:
+                # terminal overflow: every later dispatch was
+                # state-preserving.  Restage undecided requests in
+                # arrival order — newest offer pushed first so the
+                # oldest tail ends up at the ring head.
+                for later in reversed(inflight[ci + 1:]):
+                    self.counters["chunks"] -= len(later["batches"])
+                    self._restage_tail(0, later["batches"],
+                                       later["valids"], later["ltas"])
+                    del later["decs"][:], later["batches"][:], \
+                        later["valids"][:]
+                k = ctx["fail_k"]
+                self._restage_tail(k, ctx["batches"], ctx["valids"],
+                                   ctx["ltas"])
+                del ctx["decs"][k:], ctx["batches"][k:], \
+                    ctx["valids"][k:]
+                return err
+        return None
 
     def _replay_chunks(self, j: int, ctx: dict, *,
                        rollback: bool) -> Optional[Exception]:
@@ -1000,19 +1036,22 @@ class _StreamBackend(_BackendBase):
                 self.ring.snapshot() if self.ring else None)
 
     def restore(self, payload):
-        self._drain_inflight()   # settle results against the old state
-        state, ring_snap = payload
-        self._state = state
-        self._retained = True    # ...and so does a restored payload
-        self._acc_dev = None     # accumulated after the snapshot
-        if self.ring and ring_snap is not None:
-            self.ring.restore(ring_snap)
+        with jax.profiler.TraceAnnotation("repro.restore"):
+            # settle results against the old state
+            self._drain_inflight()
+            state, ring_snap = payload
+            self._state = state
+            self._retained = True    # ...and so does a restored payload
+            self._acc_dev = None     # accumulated after the snapshot
+            if self.ring and ring_snap is not None:
+                self.ring.restore(ring_snap)
 
     def _refresh_dev_metrics(self) -> None:
         """One fused device read of every state-derived counter."""
         s = self._state
         vals: Dict[str, Any] = dict(
-            n_pending=jnp.sum(s.pend_te != T_INF, dtype=jnp.int32))
+            n_pending=jnp.sum(s.pend_te != T_INF, dtype=jnp.int32),
+            **_work_counters(s))
         if self.cfg.backfilling:
             vals.update(
                 n_parked_now=jnp.sum(s.park_seq != T_INF,
@@ -1450,7 +1489,7 @@ class _EnsembleBackend(_BackendBase):
     def _refresh_dev_metrics(self) -> None:
         """One fused device read of every state-derived counter."""
         s = self.states
-        vals: Dict[str, Any] = {}
+        vals: Dict[str, Any] = _work_counters(s)
         if self.cfg.backfilling:
             vals.update(
                 n_parked_now=jnp.sum(s.park_seq != T_INF,
@@ -1462,7 +1501,7 @@ class _EnsembleBackend(_BackendBase):
             vals["tenants"] = {
                 f: getattr(s.tenants, f)
                 for f in _PER_TENANT + ("occ_ewma",)}
-        host = _device_fetch(vals) if vals else {}
+        host = _device_fetch(vals)
         self._dev_metrics = {
             k: to_host(v) if k == "tenants" else int(v)
             for k, v in host.items()}
@@ -1697,6 +1736,8 @@ class _PartitionBackend(_BackendBase):
                    partition_load=list(self.engine.load),
                    dispatches=self.engine.dispatches,
                    match_rounds=self.engine.last_match_rounds)
+        out.update({k: int(v) for k, v in jax.device_get(
+            _work_counters(self.engine.states)).items()})
         if self.cfg.backfilling:
             s = self.engine.states
             out.update(

@@ -744,7 +744,8 @@ def _no_displace(state: SchedulerState, req: RequestBatch,
     return state, search_lib.SearchResult(
         found=jnp.asarray(False), t_s=zero, t_e=zero,
         pe_mask=jnp.zeros((state.tl.words,), jnp.uint32),
-        n_free=zero, t_begin=zero, t_end=zero)
+        n_free=zero, t_begin=zero, t_end=zero,
+        early_reject=jnp.asarray(False), tiles=zero, tiles_run=zero)
 
 
 def _displace(state: SchedulerState, req: RequestBatch,
@@ -848,13 +849,12 @@ def _displace(state: SchedulerState, req: RequestBatch,
     return out, res_r._replace(found=commit)
 
 
-def _admit_impl(state: SchedulerState, req: RequestBatch,
-                policy_id: jax.Array, backfill_id, *, n_pe: int,
-                auto_release: bool,
-                use_kernel: bool = False) -> Tuple[SchedulerState, Decision]:
-    Q = state.park_capacity
-    bf = jnp.asarray(backfill_id, jnp.int32)
-    backfilling = bool(Q) and auto_release
+def _admit_release(state: SchedulerState, req: RequestBatch,
+                   bf: jax.Array, *, n_pe: int, backfilling: bool,
+                   auto_release: bool,
+                   use_kernel: bool) -> SchedulerState:
+    """The admit step's queue work: release due reservations, and on
+    backfilling states promote and retry the deferral queue."""
     if backfilling:
         # promote-due + release + retry sweep under ONE queue-work
         # cond (DESIGN.md §7): a step whose queue holds nothing due
@@ -878,101 +878,130 @@ def _admit_impl(state: SchedulerState, req: RequestBatch,
             queue_pred, queue_work,
             lambda s: _release_pending(s, t_now), state)
         # the retry latch is consumed per admit step either way
-        state = state._replace(park_retry=jnp.asarray(False))
-    elif auto_release:
-        state = release_due(state, req.t_a)
+        return state._replace(park_retry=jnp.asarray(False))
+    if auto_release:
+        return release_due(state, req.t_a)
+    return state
+
+
+def _admit_impl(state: SchedulerState, req: RequestBatch,
+                policy_id: jax.Array, backfill_id, *, n_pe: int,
+                auto_release: bool,
+                use_kernel: bool = False) -> Tuple[SchedulerState, Decision]:
+    Q = state.park_capacity
+    bf = jnp.asarray(backfill_id, jnp.int32)
+    backfilling = bool(Q) and auto_release
+    with jax.named_scope("admit.release"):
+        state = _admit_release(state, req, bf, n_pe=n_pe,
+                               backfilling=backfilling,
+                               auto_release=auto_release,
+                               use_kernel=use_kernel)
     tenancy = state.tenants is not None
     # tenancy needs the pending buffer as its reservation ledger even
     # without auto-release (overdue reaping batch-deletes from it;
     # client cancels clear it); zero-tenant callers keep their exact
     # pre-tenancy graphs.
     track_pending = auto_release or tenancy
-    if tenancy:
-        # ---- quota gate (DESIGN.md §10): after queue work — the
-        # gate must see post-release live counts, like the host
-        # oracle — but strictly *before* search.
-        tn0 = state.tenants
-        T = tn0.n_tenants
-        tid = jnp.clip(
-            jnp.asarray(0 if req.tenant is None else req.tenant,
-                        jnp.int32), 0, T - 1)
-        # filler padding (requests_to_batch rings/grids) asks for
-        # n_pe + 1 PEs; it belongs to no tenant and must neither be
-        # gated nor charged
-        real = req.n_pe <= jnp.int32(n_pe)
-        demand = (req.n_pe.astype(jnp.float32)
-                  * req.t_du.astype(jnp.float32))
-        orig_tr, orig_tdu = req.t_r, req.t_du
-        occ_row = tl_lib.occupancy_at(
-            state.tl, jnp.asarray(req.t_a, jnp.int32))
-        if state.rspec is not None:
-            # telemetry stays a PE-utilisation fraction: count only
-            # the primary plane's words of the multi-resource row
-            occ_row = occ_row[state.rspec.plane_slice(0)]
-        occ_q = tenancy_lib.ratio_q16(
-            jax.lax.population_count(occ_row).sum().astype(jnp.int32),
-            n_pe)
-        within = ((tn0.used[tid] + demand <= tn0.quota[tid])
-                  & (tn0.live[tid] < tn0.max_live[tid]))
-        blocked = real & ~within
-        # an over-quota request is rewritten never-feasible (the
-        # filler trick): search, displacement, commit and park all
-        # no-op naturally, with zero extra branches in the hot path
-        req = req._replace(
-            t_r=jnp.where(blocked, req.t_a, req.t_r),
-            t_du=jnp.where(blocked, jnp.int32(1), req.t_du),
-            t_dl=jnp.where(blocked, req.t_a + jnp.int32(1),
-                           req.t_dl),
-            n_pe=jnp.where(blocked, jnp.int32(n_pe + 1), req.n_pe))
-    else:
-        blocked = jnp.asarray(False)
+    with jax.named_scope("admit.quota"):
+        if tenancy:
+            # ---- quota gate (DESIGN.md §10): after queue work — the
+            # gate must see post-release live counts, like the host
+            # oracle — but strictly *before* search.
+            tn0 = state.tenants
+            T = tn0.n_tenants
+            tid = jnp.clip(
+                jnp.asarray(0 if req.tenant is None else req.tenant,
+                            jnp.int32), 0, T - 1)
+            # filler padding (requests_to_batch rings/grids) asks for
+            # n_pe + 1 PEs; it belongs to no tenant and must neither be
+            # gated nor charged
+            real = req.n_pe <= jnp.int32(n_pe)
+            demand = (req.n_pe.astype(jnp.float32)
+                      * req.t_du.astype(jnp.float32))
+            orig_tr, orig_tdu = req.t_r, req.t_du
+            occ_row = tl_lib.occupancy_at(
+                state.tl, jnp.asarray(req.t_a, jnp.int32))
+            if state.rspec is not None:
+                # telemetry stays a PE-utilisation fraction: count only
+                # the primary plane's words of the multi-resource row
+                occ_row = occ_row[state.rspec.plane_slice(0)]
+            occ_q = tenancy_lib.ratio_q16(
+                jax.lax.population_count(occ_row).sum().astype(jnp.int32),
+                n_pe)
+            within = ((tn0.used[tid] + demand <= tn0.quota[tid])
+                      & (tn0.live[tid] < tn0.max_live[tid]))
+            blocked = real & ~within
+            # an over-quota request is rewritten never-feasible (the
+            # filler trick): search, displacement, commit and park all
+            # no-op naturally, with zero extra branches in the hot path
+            req = req._replace(
+                t_r=jnp.where(blocked, req.t_a, req.t_r),
+                t_du=jnp.where(blocked, jnp.int32(1), req.t_du),
+                t_dl=jnp.where(blocked, req.t_a + jnp.int32(1),
+                               req.t_dl),
+                n_pe=jnp.where(blocked, jnp.int32(n_pe + 1), req.n_pe))
+        else:
+            blocked = jnp.asarray(False)
     # NB: searches at full capacity S — the per-request engine's
     # power-of-two bucketing needs the host-visible record count, which
     # does not exist inside a fixed-shape scan.  The fusion win (no
     # host round-trips) dominates; keep initial `capacity` modest and
     # let overflow growth size S to the workload.
-    res = search_lib.search(
-        state.tl, req.t_r, req.t_du, req.t_dl, req.n_pe, policy_id,
-        req.t_a, n_pe=n_pe, use_kernel=use_kernel, rspec=state.rspec,
-        demand_tail=req.demand, valid_mask=state.lane_valid)
-    # reject a win whose end clamps to the horizon sentinel: committing
-    # it would be a silent no-op under timeline.update's T_INF guard,
-    # leaving an "accepted" decision with no occupancy behind it
-    found = (res.found & ~state.overflow
-             & (res.t_e < jnp.int32(T_INF)))
-    t_s, t_e, pe_mask = res.t_s, res.t_e, res.pe_mask
-    n_free, t_begin, t_end = res.n_free, res.t_begin, res.t_end
+    with jax.named_scope("admit.search"):
+        res = search_lib.search(
+            state.tl, req.t_r, req.t_du, req.t_dl, req.n_pe, policy_id,
+            req.t_a, n_pe=n_pe, use_kernel=use_kernel, rspec=state.rspec,
+            demand_tail=req.demand, valid_mask=state.lane_valid)
+        # reject a win whose end clamps to the horizon sentinel: committing
+        # it would be a silent no-op under timeline.update's T_INF guard,
+        # leaving an "accepted" decision with no occupancy behind it
+        found = (res.found & ~state.overflow
+                 & (res.t_e < jnp.int32(T_INF)))
+        t_s, t_e, pe_mask = res.t_s, res.t_e, res.pe_mask
+        n_free, t_begin, t_end = res.n_free, res.t_begin, res.t_end
+        # the index's and the kernel's work on real requests: filler
+        # and over-quota rewrites ask for n_pe + 1 PEs, which the
+        # index's capacity proof rejects every time
+        counted = (req.n_pe <= jnp.int32(n_pe)) & ~state.overflow
+        state = state._replace(
+            n_early_rejects=state.n_early_rejects
+            + (counted & res.early_reject).astype(jnp.int32),
+            n_search_tiles=state.n_search_tiles
+            + jnp.where(counted, res.tiles, 0),
+            n_search_tiles_run=state.n_search_tiles_run
+            + jnp.where(counted, res.tiles_run, 0))
     need_add = jnp.asarray(True)
-    if backfilling:
-        # EASY fallback: an otherwise-rejected request may displace
-        # non-head parked reservations (transactional; see _displace).
-        # With fewer than two live entries there is nothing to lift —
-        # the transaction would re-run the identical failed search —
-        # so it is skipped (identical decisions, no wasted searches).
-        # over-quota requests never displace: the transaction's lifts
-        # could latch overflow for work the gate already rejected
-        can_try = ((bf == BF_EASY) & ~res.found & ~state.overflow
-                   & ~blocked
-                   & (jnp.sum(state.park_seq < T_INF) >= 2))
-        state, dres = jax.lax.cond(
-            can_try,
-            functools.partial(_displace, n_pe=n_pe,
-                              use_kernel=use_kernel),
-            _no_displace, state, req, policy_id)
-        found = jnp.where(can_try, dres.found, found)
-        t_s = jnp.where(can_try, dres.t_s, t_s)
-        t_e = jnp.where(can_try, dres.t_e, t_e)
-        pe_mask = jnp.where(can_try, dres.pe_mask, pe_mask)
-        n_free = jnp.where(can_try, dres.n_free, n_free)
-        t_begin = jnp.where(can_try, dres.t_begin, t_begin)
-        t_end = jnp.where(can_try, dres.t_end, t_end)
-        # the displacement transaction already wrote r to the timeline
-        need_add = ~can_try
-        free_park = state.park_seq == jnp.int32(T_INF)
-        parks = ((bf != BF_NONE) & (t_s > req.t_r)
-                 & jnp.any(free_park))
-    else:
-        parks = jnp.asarray(False)
+    with jax.named_scope("admit.displace"):
+        if backfilling:
+            # EASY fallback: an otherwise-rejected request may displace
+            # non-head parked reservations (transactional; see _displace).
+            # With fewer than two live entries there is nothing to lift —
+            # the transaction would re-run the identical failed search —
+            # so it is skipped (identical decisions, no wasted searches).
+            # over-quota requests never displace: the transaction's lifts
+            # could latch overflow for work the gate already rejected
+            can_try = ((bf == BF_EASY) & ~res.found & ~state.overflow
+                       & ~blocked
+                       & (jnp.sum(state.park_seq < T_INF) >= 2))
+            state, dres = jax.lax.cond(
+                can_try,
+                functools.partial(_displace, n_pe=n_pe,
+                                  use_kernel=use_kernel),
+                _no_displace, state, req, policy_id)
+            found = jnp.where(can_try, dres.found, found)
+            t_s = jnp.where(can_try, dres.t_s, t_s)
+            t_e = jnp.where(can_try, dres.t_e, t_e)
+            pe_mask = jnp.where(can_try, dres.pe_mask, pe_mask)
+            n_free = jnp.where(can_try, dres.n_free, n_free)
+            t_begin = jnp.where(can_try, dres.t_begin, t_begin)
+            t_end = jnp.where(can_try, dres.t_end, t_end)
+            # the displacement transaction already wrote r to the timeline
+            need_add = ~can_try
+            free_park = state.park_seq == jnp.int32(T_INF)
+            parks = ((bf != BF_NONE) & (t_s > req.t_r)
+                     & jnp.any(free_park))
+        else:
+            parks = jnp.asarray(False)
 
     def commit(s: SchedulerState) -> SchedulerState:
         new_tl, ovf, n_keep = tl_lib.update(
@@ -1062,45 +1091,47 @@ def _admit_impl(state: SchedulerState, req: RequestBatch,
                                lambda o: o, out)
         return out
 
-    state = jax.lax.cond(found, commit, lambda s: s, state)
-    accepted = found & ~state.overflow
-    if tenancy:
-        # ---- per-tenant accounting and telemetry EWMAs: lazy
-        # device-resident accumulators (one scatter block per step,
-        # nothing read back).  Filler padding (real=False) and
-        # overflowed steps (re-run from the pre-run snapshot anyway)
-        # charge nothing, so the table matches the host oracle, which
-        # sees neither.  `used` mirrors HostTenantAccounts.record
-        # float32-for-float32; the EWMAs are int32 fixed point.
-        tn = state.tenants
-        ok_upd = real & ~state.overflow
-        a = tn.alpha
-        acc_i = jnp.where(ok_upd & accepted, 1, 0).astype(jnp.int32)
-        rej_i = jnp.where(ok_upd & ~accepted, 1, 0).astype(jnp.int32)
-        qrej_i = jnp.where(ok_upd & blocked, 1, 0).astype(jnp.int32)
-        prk_i = jnp.where(ok_upd & accepted & parks, 1,
-                          0).astype(jnp.int32)
-        acc_x = jnp.where(accepted, tenancy_lib.EWMA_ONE, 0)
-        new_acc = tenancy_lib.ewma_q16(tn.acc_ewma[tid], acc_x, a)
-        slow_x = tenancy_lib.ratio_q16(t_e - orig_tr, orig_tdu)
-        new_slow = tenancy_lib.ewma_q16(tn.slow_ewma[tid], slow_x, a)
-        new_occ = tenancy_lib.ewma_q16(tn.occ_ewma, occ_q, a)
-        state = state._replace(tenants=tn._replace(
-            used=tn.used.at[tid].add(
-                jnp.where(ok_upd & accepted, demand,
-                          jnp.float32(0.0))),
-            live=tn.live.at[tid].add(acc_i),
-            n_accepted=tn.n_accepted.at[tid].add(acc_i),
-            n_rejected=tn.n_rejected.at[tid].add(rej_i),
-            n_quota_rejected=tn.n_quota_rejected.at[tid].add(qrej_i),
-            n_parked=tn.n_parked.at[tid].add(prk_i),
-            acc_ewma=tn.acc_ewma.at[tid].set(
-                jnp.where(ok_upd, new_acc, tn.acc_ewma[tid])),
-            slow_ewma=tn.slow_ewma.at[tid].set(
-                jnp.where(ok_upd & accepted, new_slow,
-                          tn.slow_ewma[tid])),
-            occ_ewma=jnp.where(ok_upd, new_occ, tn.occ_ewma),
-        ))
+    with jax.named_scope("admit.commit"):
+        state = jax.lax.cond(found, commit, lambda s: s, state)
+        accepted = found & ~state.overflow
+    with jax.named_scope("admit.quota"):
+        if tenancy:
+            # ---- per-tenant accounting and telemetry EWMAs: lazy
+            # device-resident accumulators (one scatter block per step,
+            # nothing read back).  Filler padding (real=False) and
+            # overflowed steps (re-run from the pre-run snapshot anyway)
+            # charge nothing, so the table matches the host oracle, which
+            # sees neither.  `used` mirrors HostTenantAccounts.record
+            # float32-for-float32; the EWMAs are int32 fixed point.
+            tn = state.tenants
+            ok_upd = real & ~state.overflow
+            a = tn.alpha
+            acc_i = jnp.where(ok_upd & accepted, 1, 0).astype(jnp.int32)
+            rej_i = jnp.where(ok_upd & ~accepted, 1, 0).astype(jnp.int32)
+            qrej_i = jnp.where(ok_upd & blocked, 1, 0).astype(jnp.int32)
+            prk_i = jnp.where(ok_upd & accepted & parks, 1,
+                              0).astype(jnp.int32)
+            acc_x = jnp.where(accepted, tenancy_lib.EWMA_ONE, 0)
+            new_acc = tenancy_lib.ewma_q16(tn.acc_ewma[tid], acc_x, a)
+            slow_x = tenancy_lib.ratio_q16(t_e - orig_tr, orig_tdu)
+            new_slow = tenancy_lib.ewma_q16(tn.slow_ewma[tid], slow_x, a)
+            new_occ = tenancy_lib.ewma_q16(tn.occ_ewma, occ_q, a)
+            state = state._replace(tenants=tn._replace(
+                used=tn.used.at[tid].add(
+                    jnp.where(ok_upd & accepted, demand,
+                              jnp.float32(0.0))),
+                live=tn.live.at[tid].add(acc_i),
+                n_accepted=tn.n_accepted.at[tid].add(acc_i),
+                n_rejected=tn.n_rejected.at[tid].add(rej_i),
+                n_quota_rejected=tn.n_quota_rejected.at[tid].add(qrej_i),
+                n_parked=tn.n_parked.at[tid].add(prk_i),
+                acc_ewma=tn.acc_ewma.at[tid].set(
+                    jnp.where(ok_upd, new_acc, tn.acc_ewma[tid])),
+                slow_ewma=tn.slow_ewma.at[tid].set(
+                    jnp.where(ok_upd & accepted, new_slow,
+                              tn.slow_ewma[tid])),
+                occ_ewma=jnp.where(ok_upd, new_occ, tn.occ_ewma),
+            ))
     return state, Decision(
         accepted=accepted,
         t_s=jnp.where(accepted, t_s, jnp.int32(-1)),
@@ -1127,8 +1158,10 @@ def admit(state: SchedulerState, req: RequestBatch,
     conservative); it only matters when the state carries a deferral
     queue (``park_capacity > 0``).
     """
-    return _admit_impl(state, req, policy_id, backfill_id, n_pe=n_pe,
-                       auto_release=auto_release, use_kernel=use_kernel)
+    with jax.named_scope("admit"):
+        return _admit_impl(state, req, policy_id, backfill_id,
+                           n_pe=n_pe, auto_release=auto_release,
+                           use_kernel=use_kernel)
 
 
 @functools.partial(
@@ -1142,9 +1175,10 @@ def admit_stream(state: SchedulerState, batch: RequestBatch,
     bf = jnp.asarray(backfill_id, jnp.int32)
 
     def step(s, r):
-        return _admit_impl(s, r, policy_id, bf, n_pe=n_pe,
-                           auto_release=auto_release,
-                           use_kernel=use_kernel)
+        with jax.named_scope("admit"):
+            return _admit_impl(s, r, policy_id, bf, n_pe=n_pe,
+                               auto_release=auto_release,
+                               use_kernel=use_kernel)
 
     return jax.lax.scan(step, state, batch)
 
@@ -1179,9 +1213,10 @@ def admit_stream_donated(state: SchedulerState, batch: RequestBatch,
     bf = jnp.asarray(backfill_id, jnp.int32)
 
     def step(s, r):
-        return _admit_impl(s, r, policy_id, bf, n_pe=n_pe,
-                           auto_release=auto_release,
-                           use_kernel=use_kernel)
+        with jax.named_scope("admit"):
+            return _admit_impl(s, r, policy_id, bf, n_pe=n_pe,
+                               auto_release=auto_release,
+                               use_kernel=use_kernel)
 
     out, dec = jax.lax.scan(step, state, batch)
     ovf = state.overflow | out.overflow

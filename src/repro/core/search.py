@@ -37,7 +37,10 @@ class SearchResult(NamedTuple):
     n_free: jax.Array     # int32 free PEs in the winning rectangle
     t_begin: jax.Array    # int32 rectangle begin
     t_end: jax.Array      # int32 rectangle end
-
+    # work counts the admit step folds into its state counters
+    early_reject: jax.Array  # bool: the index's summary_reject fired
+    tiles: jax.Array      # int32 candidate tiles the kernel covered
+    tiles_run: jax.Array  # int32 of those, tiles with a live candidate
 
 class Rectangles(NamedTuple):
     """Per-candidate maximum availability rectangles.
@@ -341,6 +344,11 @@ def search(
     shape, so pruning there saves nothing).  Both are conservative
     (summary-infeasible implies truly infeasible), so every result
     stays bit-identical to the index-free search.
+
+    The result also carries the search's work counts: whether the
+    early reject fired, and on the kernel path the candidate tiles the
+    kernel covered and those it ran (0 on the jnp path).  Its phases
+    carry the ``admit.search.*`` named scopes (DESIGN.md §13).
     """
     if rspec is not None:
         if valid_mask is None:
@@ -349,32 +357,36 @@ def search(
             demand_tail = jnp.zeros((rspec.R - 1,), jnp.int32)
         demand_tail = jnp.asarray(demand_tail, jnp.int32)
     if tl.ispec is not None:
-        demand_vec = _index_demand(tl.ispec, n_req, demand_tail)
-        deficit = idx_lib.plane_deficit(tl.ispec, valid_mask)
-        reject = summary_reject(tl, t_r, t_du, t_dl, demand_vec,
-                                deficit)
+        with jax.named_scope("admit.search.reject"):
+            demand_vec = _index_demand(tl.ispec, n_req, demand_tail)
+            deficit = idx_lib.plane_deficit(tl.ispec, valid_mask)
+            reject = summary_reject(tl, t_r, t_du, t_dl, demand_vec,
+                                    deficit)
 
         def _rejected(_):
             # bit-exact cheap branch: selection over an all-infeasible
             # candidate set falls back to index 0, whose start is the
             # minimum live candidate — min(t_r, t_dl - t_du) — and the
             # rejected Decision reports that candidate's rectangle
-            starts0 = jnp.minimum(
-                jnp.asarray(t_r, jnp.int32),
-                jnp.asarray(t_dl, jnp.int32)
-                - jnp.asarray(t_du, jnp.int32))[None]
-            rects = availability_rectangles(
-                tl, starts0, t_du, t_now, n_pe, rspec=rspec,
-                valid_mask=valid_mask)
-            return SearchResult(
-                found=jnp.asarray(False),
-                t_s=starts0[0],
-                t_e=starts0[0] + jnp.asarray(t_du, jnp.int32),
-                pe_mask=jnp.zeros((tl.words,), jnp.uint32),
-                n_free=rects.n_free[0],
-                t_begin=rects.t_begin[0],
-                t_end=rects.t_end[0],
-            )
+            with jax.named_scope("admit.search.reject"):
+                starts0 = jnp.minimum(
+                    jnp.asarray(t_r, jnp.int32),
+                    jnp.asarray(t_dl, jnp.int32)
+                    - jnp.asarray(t_du, jnp.int32))[None]
+                rects = availability_rectangles(
+                    tl, starts0, t_du, t_now, n_pe, rspec=rspec,
+                    valid_mask=valid_mask)
+                return SearchResult(
+                    found=jnp.asarray(False),
+                    t_s=starts0[0],
+                    t_e=starts0[0] + jnp.asarray(t_du, jnp.int32),
+                    pe_mask=jnp.zeros((tl.words,), jnp.uint32),
+                    n_free=rects.n_free[0],
+                    t_begin=rects.t_begin[0],
+                    t_end=rects.t_end[0],
+                    early_reject=jnp.asarray(True), tiles=jnp.int32(0),
+                    tiles_run=jnp.int32(0),
+                )
 
         def _full(_):
             return _search_full(
@@ -408,69 +420,74 @@ def _search_full(
     deficit: Optional[jax.Array],
 ) -> SearchResult:
     """The candidate enumeration half of :func:`search` (see there)."""
-    starts = candidate_starts(tl, t_r, t_du, t_dl)
-    if tl.ispec is not None and use_kernel:
-        # summary pruning feeds the availscan kernels' data-driven
-        # tile skip: a pruned start becomes T_INF padding, so its
-        # tile never loads.  The jnp reference path evaluates every
-        # candidate slot at fixed shape regardless, so pruning there
-        # is pure per-request cost — the mask changes nothing the
-        # where-select downstream wouldn't (pruned candidates are
-        # truly infeasible and could never win selection either way).
-        starts = prune_candidates(tl, starts, t_du, demand_vec,
-                                  deficit)
+    with jax.named_scope("admit.search.candidates"):
+        starts = candidate_starts(tl, t_r, t_du, t_dl)
+        if tl.ispec is not None and use_kernel:
+            # summary pruning feeds the availscan kernels' data-driven
+            # tile skip: a pruned start becomes T_INF padding, so its
+            # tile never loads.  The jnp reference path evaluates
+            # every candidate slot at fixed shape regardless, so
+            # pruning there is pure per-request cost — the mask
+            # changes nothing the where-select downstream wouldn't
+            # (pruned candidates are truly infeasible and could never
+            # win selection either way).
+            starts = prune_candidates(tl, starts, t_du, demand_vec,
+                                      deficit)
+    sel = None
     if use_kernel:
         from repro.kernels import ops as kernel_ops
         # fused path: rectangles + policy selection in one kernel —
         # the per-candidate vectors never round-trip through HBM
-        sel = kernel_ops.search_select(
-            tl, starts, t_du, t_now, n_req, policy_id, n_pe=n_pe,
-            rspec=rspec, demand_tail=demand_tail,
-            valid_mask=valid_mask)
-        if sel is not None:
-            found = sel["found"]
-            t_s = starts[sel["best"]]
-            if rspec is None:
-                pe_mask = _winning_pe_mask(tl, t_s, t_du, n_req, n_pe)
-            else:
-                pe_mask = _winning_mask_mr(
-                    tl, t_s, t_du, n_req, demand_tail, rspec,
-                    valid_mask)
-            return SearchResult(
-                found=found,
-                t_s=t_s,
-                t_e=t_s + t_du,
-                pe_mask=jnp.where(found, pe_mask, jnp.uint32(0)),
-                n_free=sel["n_free"],
-                t_begin=sel["t_begin"],
-                t_end=sel["t_end"],
-            )
-    # jnp reference path — also the fallback when search_select
-    # returned None (shape beyond the kernel VMEM budget; the unfused
-    # kernel entry exists for the element-wise oracle tests)
-    rects = availability_rectangles(tl, starts, t_du, t_now, n_pe,
-                                    rspec=rspec, valid_mask=valid_mask)
-    feasible = rects.valid & (rects.n_free >= n_req)
-    if rspec is not None and rspec.R > 1:
-        feasible = feasible & jnp.all(
-            rects.n_free_tail >= demand_tail[None, :], axis=1)
-    duration = rects.t_end - rects.t_begin
-    best, found = policies_lib.select(
-        policy_id, rects.n_free, duration, rects.starts, feasible)
-    t_s = rects.starts[best]
-    if rspec is None:
-        pe_mask = _winning_pe_mask(tl, t_s, t_du, n_req, n_pe)
+        with jax.named_scope("admit.search.rects"):
+            sel = kernel_ops.search_select(
+                tl, starts, t_du, t_now, n_req, policy_id, n_pe=n_pe,
+                rspec=rspec, demand_tail=demand_tail,
+                valid_mask=valid_mask)
+    if sel is not None:
+        found = sel["found"]
+        t_s = starts[sel["best"]]
+        n_free, t_begin, t_end = sel["n_free"], sel["t_begin"], \
+            sel["t_end"]
+        work = dict(early_reject=jnp.asarray(False), tiles=sel["tiles"],
+                    tiles_run=sel["tiles_run"])
     else:
-        pe_mask = _winning_mask_mr(
-            tl, t_s, t_du, n_req, demand_tail, rspec, valid_mask)
+        # jnp reference path — also the fallback when search_select
+        # returned None (shape beyond the kernel VMEM budget; the
+        # unfused kernel entry exists for the element-wise oracle
+        # tests)
+        with jax.named_scope("admit.search.rects"):
+            rects = availability_rectangles(
+                tl, starts, t_du, t_now, n_pe, rspec=rspec,
+                valid_mask=valid_mask)
+            feasible = rects.valid & (rects.n_free >= n_req)
+            if rspec is not None and rspec.R > 1:
+                feasible = feasible & jnp.all(
+                    rects.n_free_tail >= demand_tail[None, :], axis=1)
+            duration = rects.t_end - rects.t_begin
+            best, found = policies_lib.select(
+                policy_id, rects.n_free, duration, rects.starts,
+                feasible)
+            t_s = rects.starts[best]
+            n_free, t_begin, t_end = rects.n_free[best], \
+                rects.t_begin[best], rects.t_end[best]
+        work = dict(early_reject=jnp.asarray(False),
+                    tiles=jnp.int32(0), tiles_run=jnp.int32(0))
+    with jax.named_scope("admit.search.mask"):
+        if rspec is None:
+            pe_mask = _winning_pe_mask(tl, t_s, t_du, n_req, n_pe)
+        else:
+            pe_mask = _winning_mask_mr(
+                tl, t_s, t_du, n_req, demand_tail, rspec, valid_mask)
+        pe_mask = jnp.where(found, pe_mask, jnp.uint32(0))
     return SearchResult(
         found=found,
         t_s=t_s,
         t_e=t_s + t_du,
-        pe_mask=jnp.where(found, pe_mask, jnp.uint32(0)),
-        n_free=rects.n_free[best],
-        t_begin=rects.t_begin[best],
-        t_end=rects.t_end[best],
+        pe_mask=pe_mask,
+        n_free=n_free,
+        t_begin=t_begin,
+        t_end=t_end,
+        **work,
     )
 
 

@@ -164,6 +164,13 @@ class SchedulerState(NamedTuple):
     pend_mask: jax.Array  # uint32[K, W] reserved-PE bitmasks
     n_accepted: jax.Array  # int32 scalar
     n_released: jax.Array  # int32 scalar
+    n_early_rejects: jax.Array  # int32 scalar: real requests the
+    #                             index's summary_reject rejected
+    n_search_tiles: jax.Array   # int32 scalar: candidate tiles the
+    #                             admit searches' kernel covered
+    n_search_tiles_run: jax.Array  # int32 scalar: of those, tiles
+    #                                with a live candidate (the rest
+    #                                were skipped)
     overflow: jax.Array    # bool scalar
     hw_records: jax.Array  # int32 scalar: max records any update needed
     hw_pending: jax.Array  # int32 scalar: max pending slots needed
@@ -262,6 +269,9 @@ def init_state(capacity: int, n_pe: int,
                             jnp.uint32),
         n_accepted=jnp.int32(0),
         n_released=jnp.int32(0),
+        n_early_rejects=jnp.int32(0),
+        n_search_tiles=jnp.int32(0),
+        n_search_tiles_run=jnp.int32(0),
         overflow=jnp.asarray(False),
         hw_records=jnp.int32(0),
         hw_pending=jnp.int32(0),

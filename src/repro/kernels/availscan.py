@@ -125,6 +125,16 @@ def _tile_live(live: jax.Array, P_pad: int, pt: int) -> jax.Array:
     return jnp.sum(lv.reshape(P_pad // pt, pt), axis=1)
 
 
+def tile_counts(live: jax.Array, pt: int = DEFAULT_PT) -> dict:
+    """``tiles``: the candidate tiles a kernel grid over ``live``
+    covers; ``tiles_run``: those with a live candidate (the others
+    skip their MXU work)."""
+    P_pad = -(-live.shape[-1] // pt) * pt
+    tlive = _tile_live(live, P_pad, pt)
+    return dict(tiles=jnp.int32(P_pad // pt),
+                tiles_run=jnp.sum(tlive > 0, dtype=jnp.int32))
+
+
 def _availscan_kernel(tlive_ref, a_ref, b_ref, times_ref, nxt_ref,
                       occ_ref, nfree_ref, tb_ref, te_ref, *, pt):
     i = pl.program_id(0)

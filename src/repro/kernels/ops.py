@@ -175,7 +175,9 @@ def search_select(
     falls back to the jnp chain); otherwise a dict with the winning
     candidate: ``found``, ``best`` (index into ``starts``) and its
     post-processed ``n_free`` / ``t_begin`` / ``t_end`` — bit-identical
-    to ``availability_rectangles`` + ``policies.select``.
+    to ``availability_rectangles`` + ``policies.select`` — plus
+    ``tiles`` (candidate tiles the kernel's grid covers) and
+    ``tiles_run`` (those holding a live candidate; the rest skip).
 
     ``rspec`` dispatches to the multi-resource kernel: the demand tail
     joins the scalar-prefetch row and feasibility AND-reduces across
@@ -200,7 +202,7 @@ def search_select(
             occ_bits, psel, times, nxt, starts, a, b, scalars, live,
             n_res=rspec.R)
         return dict(found=acc[7] > 0, best=acc[3], n_free=acc[4],
-                    t_begin=acc[5], t_end=acc[6])
+                    t_begin=acc[5], t_end=acc[6], **_k.tile_counts(live))
     ops = _padded_operands(tl, n_pe)
     if ops is None:
         return None
@@ -215,4 +217,4 @@ def search_select(
     acc = _k.availscan_select(
         occ_bits, times, nxt, starts, a, b, scalars, live)
     return dict(found=acc[7] > 0, best=acc[3], n_free=acc[4],
-                t_begin=acc[5], t_end=acc[6])
+                t_begin=acc[5], t_end=acc[6], **_k.tile_counts(live))
