@@ -209,12 +209,33 @@ def _work_counters(s) -> Dict[str, jax.Array]:
                 search_tiles_skipped=tiles - jnp.sum(s.n_search_tiles_run))
 
 
+# chunks a pipelined offer joins per group while its scans still run
+_SETTLE_GROUP = 16
+
+
 def _concat_tree(chunks: List[Any], axis: int):
     """Concatenate a list of equally-structured pytrees."""
     if len(chunks) == 1:
         return chunks[0]
     return jax.tree_util.tree_map(
         lambda *xs: jnp.concatenate(xs, axis=axis), *chunks)
+
+
+@jax.jit
+def _join_group(*chunks):
+    """One full settle group's chunks, joined in a single dispatch.
+
+    Its arity is always ``_SETTLE_GROUP``, so it compiles once per
+    chunk shape; a shorter last group is joined eagerly instead.
+    """
+    return _concat_tree(list(chunks), axis=0)
+
+
+def _accepted_count(decision, valid) -> jax.Array:
+    """Device count of the valid accepted decisions (no host sync)."""
+    return jnp.sum(jnp.logical_and(jnp.asarray(decision.accepted),
+                                   jnp.asarray(valid)),
+                   dtype=jnp.int32)
 
 
 def _push_front(ring: RequestRing, rows: List[dict], lta: int) -> int:
@@ -252,7 +273,8 @@ class Session:
         cfg = self.config
         self._counters = dict(offered=0, accepted=0, released=0,
                               reaped=0, cancelled=0, chunks=0,
-                              growths=0, one_shot_scans=0)
+                              growths=0, one_shot_scans=0,
+                              settled_ahead=0, settled_after_replay=0)
         self._backend = _make_backend(cfg, self._counters)
 
     # -- identity ------------------------------------------------------
@@ -372,6 +394,10 @@ class Session:
         kernel's budget), and the admit searches' work counters
         ``early_rejects``, ``search_tiles`` and ``search_tiles_skipped``
         (DESIGN.md §13), which rewind with :meth:`restore`.
+        ``settled_ahead`` counts pipelined offers whose results,
+        prepared while their chunks ran, were installed unchanged;
+        ``settled_after_replay`` those concatenated again after a
+        growth replay (DESIGN.md §9).
 
         On multi-tenant sessions the ``"tenants"`` key carries the
         per-tenant telemetry arrays (weights, quotas, usage, live
@@ -522,10 +548,9 @@ class _BackendBase:
         folds the accumulator into ``counters["accepted"]`` — this is
         what keeps ``offer`` free of per-call device round-trips.
         """
-        n = jnp.sum(
-            jnp.logical_and(jnp.asarray(decision.accepted),
-                            jnp.asarray(valid)),
-            dtype=jnp.int32)
+        self._fold_accepted(_accepted_count(decision, valid))
+
+    def _fold_accepted(self, n: jax.Array) -> None:
         self._acc_dev = n if self._acc_dev is None else \
             self._acc_dev + n
 
@@ -770,6 +795,13 @@ class _StreamBackend(_BackendBase):
         first latched chunk, so the tail replays deterministically on
         a grown state — decisions bit-identical to the eager
         per-chunk path.
+
+        The settling work is queued here, behind the offer's own scans
+        while the device still runs them: every ``_SETTLE_GROUP``
+        chunks' decisions and batches are joined into one group, and
+        the offer ends by joining the groups and counting its accepted
+        requests.  The drain installs these prepared results unless a
+        replay rewrote the offer's decisions.
         """
         chunk = self.cfg.chunk_size
         decs: List[Decision] = []
@@ -777,7 +809,18 @@ class _StreamBackend(_BackendBase):
         valids: List[np.ndarray] = []
         ovfs: List[jax.Array] = []
         ltas: List[int] = [self.ring.last_popped_t_a]
+        groups: List[Tuple[Decision, RequestBatch]] = []
         staged = None
+
+        def settle_group() -> None:
+            # join the chunks dispatched since the last group; the
+            # concatenations queue behind their scans
+            lo = len(groups) * _SETTLE_GROUP
+            part = list(zip(decs[lo:], batches[lo:]))
+            with jax.profiler.TraceAnnotation("repro.offer.settle"):
+                groups.append(_join_group(*part)
+                              if len(part) == _SETTLE_GROUP
+                              else _concat_tree(part, axis=0))
 
         def stage():
             with jax.profiler.TraceAnnotation("repro.offer.stage"):
@@ -802,6 +845,8 @@ class _StreamBackend(_BackendBase):
             batches.append(batch)
             valids.append(valid)
             self.counters["chunks"] += 1
+            if len(decs) % _SETTLE_GROUP == 0:
+                settle_group()
 
         def drain(more) -> None:
             nonlocal staged
@@ -823,25 +868,36 @@ class _StreamBackend(_BackendBase):
             drain(lambda: self.ring.count > 0)
         if not decs:
             return _empty_result()
+        if len(decs) > len(groups) * _SETTLE_GROUP:
+            settle_group()
+        with jax.profiler.TraceAnnotation("repro.offer.settle"):
+            decision = _concat_tree([g[0] for g in groups], axis=0)
+            valid = np.concatenate(valids)
+            prepared = (decision,
+                        _concat_tree([g[1] for g in groups], axis=0),
+                        valid, _accepted_count(decision, valid))
         res = OfferResult(_finalize=self._drain_inflight)
         self._inflight.append(dict(ovfs=ovfs, decs=decs,
                                    batches=batches, valids=valids,
-                                   ltas=ltas, pid=pid, result=res))
+                                   ltas=ltas, pid=pid, result=res,
+                                   prepared=prepared))
         return res
 
     def _drain_inflight(self) -> None:
         """Settle every in-flight pipelined offer in one device sync.
 
-        Reads all outstanding overflow latches with a single stacked
-        ``device_get``.  In the common all-clear case every offer's
-        decisions are already correct and just need concatenating.  On
-        a latch, the sticky in-dispatch rollback made every dispatch
-        from the first latched chunk on state-preserving, so
-        ``_state`` is the pre-latch state sized by the failed tail's
-        watermarks: grow once from the rollback, replay the owning
-        offer's tail, then replay *all* chunks of every later offer
-        (their original decisions are garbage) — observably identical
-        to the eager per-chunk path.
+        The overflow latch is sticky along the chain of donated
+        dispatches (DESIGN.md §8), so the newest dispatch's latch copy
+        is set exactly when some chunk of some in-flight offer latched:
+        the common path reads that one scalar and installs each
+        offer's prepared results.  On a latch, every latch is read to
+        find the first latched chunk.  The sticky in-dispatch rollback
+        made every dispatch from it on state-preserving, so ``_state``
+        is the pre-latch state sized by the failed tail's watermarks:
+        grow once from the rollback, replay the owning offer's tail,
+        then replay *all* chunks of every later offer (their original
+        decisions are garbage, and so are their prepared results) —
+        observably identical to the eager per-chunk path.
         """
         if not self._inflight:
             return
@@ -850,23 +906,31 @@ class _StreamBackend(_BackendBase):
 
     def _drain(self, inflight: List[dict]) -> None:
         self._inflight = []
-        all_ovfs = [o for ctx in inflight for o in ctx["ovfs"]]
-        # the drain's single synchronization point: all latches at once
+        # the drain's single synchronization point: the newest latch
         with jax.profiler.TraceAnnotation("repro.drain.sync"):
-            latched = np.asarray(_device_fetch(jnp.stack(all_ovfs)))
+            latched = bool(_device_fetch(inflight[-1]["ovfs"][-1]))
         err = None
-        if latched.any():
+        if latched:
             with jax.profiler.TraceAnnotation("repro.drain.replay"):
-                err = self._replay_from(int(latched.argmax()), inflight)
+                all_ovfs = [o for ctx in inflight for o in ctx["ovfs"]]
+                first = np.asarray(
+                    _device_fetch(jnp.stack(all_ovfs))).argmax()
+                err = self._replay_from(int(first), inflight)
         with jax.profiler.TraceAnnotation("repro.drain.concat"):
             for ctx in inflight:
                 res = ctx["result"]
                 res._finalize = None
-                if ctx["decs"]:
+                if ctx["prepared"] is not None:
+                    res._decision, res._batch, res._valid, n = \
+                        ctx["prepared"]
+                    self._fold_accepted(n)
+                    self.counters["settled_ahead"] += 1
+                elif ctx["decs"]:
                     res._decision = _concat_tree(ctx["decs"], axis=0)
                     res._batch = _concat_tree(ctx["batches"], axis=0)
                     res._valid = np.concatenate(ctx["valids"])
                     self._defer_accepted(res._decision, res._valid)
+                    self.counters["settled_after_replay"] += 1
                 else:
                     res._allocations = []
         if err is not None:
@@ -881,6 +945,8 @@ class _StreamBackend(_BackendBase):
         while g >= len(inflight[c]["ovfs"]):
             g -= len(inflight[c]["ovfs"])
             c += 1
+        for ctx in inflight[c:]:
+            ctx["prepared"] = None     # joined from garbage decisions
         for ci in range(c, len(inflight)):
             ctx = inflight[ci]
             err = self._replay_chunks(

@@ -13,6 +13,7 @@ Acceptance gates for the streaming redesign:
 * the remaining verbs — ``tick``, ``cancel``, ``snapshot``/``restore``,
   ``metrics`` — and the ensemble / host / partition backends.
 """
+import jax
 import numpy as np
 import pytest
 
@@ -167,6 +168,110 @@ def test_offer_flush_false_stages_remainder():
     ref_acc, ref_ts, _ = _one_shot(jobs, n_pe, Policy.PE_W, 64, 256)
     np.testing.assert_array_equal(acc, ref_acc)
     np.testing.assert_array_equal(ts, ref_ts)
+
+
+# ---------------------------------------------------------------------------
+# the cross-offer drain: results settled ahead, one sticky latch read
+# ---------------------------------------------------------------------------
+
+# three in-flight pipelined offers of 8-request chunks: 17 chunks of
+# short jobs (one full settle group and a tail), then 8 short jobs and
+# 8 that all stay live, then 8 more live ones.  Small capacities latch
+# the first live chunk, the second offer's chunk 1 (dispatch 18).
+_SHORT = [ARRequest(t_a=i, t_r=i, t_du=1, t_dl=i + 4, n_pe=1)
+          for i in range(144)]
+_LIVE = [ARRequest(t_a=200 + i, t_r=200 + i, t_du=5000,
+                   t_dl=5200 + i, n_pe=1) for i in range(16)]
+_BURST = [_SHORT[:136], _SHORT[136:] + _LIVE[:8], _LIVE[8:]]
+
+
+def _drain_session(**kw):
+    return ReservationService(ServiceConfig(
+        n_pe=16, chunk_size=8, ring_capacity=64, **kw)).session()
+
+
+@pytest.mark.parametrize("case", ["clean", "growth", "terminal"])
+def test_pipelined_drain_settles_ahead_and_reads_one_latch(
+        case, monkeypatch):
+    """(a) an all-clear burst installs every offer's prepared results
+    after one latch read, bit-identical to the eager path; (b) a growth
+    latched in the second offer keeps the first offer's prepared
+    results and replays the later two, as a big-capacity session
+    decides; (c) a terminal overflow (growth budget spent) truncates
+    the second offer, restages it and the third in arrival order, and
+    counts nothing twice; (d) along the chain the newest latch copy is
+    set exactly when any is — the invariant the one-read sync uses."""
+    from repro.api import service as service_mod
+
+    small = dict(clean=dict(capacity=256, pending_capacity=256),
+                 growth=dict(capacity=8, pending_capacity=4),
+                 terminal=dict(capacity=2, pending_capacity=2,
+                               max_growths=1))[case]
+    sess = _drain_session(**small)
+    res = [sess.offer(reqs) for reqs in _BURST]
+    inflight = list(sess._backend._inflight)
+    assert len(inflight) == 3
+    prepared = [ctx["prepared"] for ctx in inflight]
+    latches = np.concatenate([np.asarray(jax.device_get(ctx["ovfs"]))
+                              for ctx in inflight])
+    # (d) sticky: once set, every later dispatch's latch stays set
+    assert latches.tolist() == sorted(latches.tolist())
+    assert bool(latches[-1]) == bool(latches.any())
+    assert latches.any() == (case != "clean")
+    if case != "clean":
+        assert int(latches.argmax()) == 18
+
+    calls = {"n": 0}
+    real = service_mod._device_fetch
+
+    def counting(tree):
+        calls["n"] += 1
+        return real(tree)
+
+    monkeypatch.setattr(service_mod, "_device_fetch", counting)
+    if case == "terminal":
+        with pytest.raises(batch_lib.GrowthError):
+            res[0].decision
+    else:
+        res[0].decision
+    # the sync reads one scalar; only a latch reads the rest
+    assert calls["n"] == (1 if case == "clean" else 2)
+    monkeypatch.setattr(service_mod, "_device_fetch", real)
+
+    kept = {"clean": 3, "growth": 1, "terminal": 1}[case]
+    for r, prep in zip(res[:kept], prepared):
+        assert r.decision is prep[0] and r.batch is prep[1]
+    for r, prep in zip(res[kept:], prepared[kept:]):
+        assert r.decision is not prep[0]
+    m = sess.metrics()
+    assert m["settled_ahead"] == kept
+    assert m["settled_after_replay"] == {
+        "clean": 0, "growth": 2, "terminal": 1}[case]
+
+    if case == "terminal":
+        # offer 2 decided its short chunk only; its live chunk and all
+        # of offer 3 wait at the ring front, in arrival order
+        assert [r.n_offered for r in res] == [136, 8, 0]
+        assert m["accepted"] == 144 and m["chunks"] == 18
+        assert m["offered"] == 160
+        ring = sess._backend.ring
+        batch, valid = ring.pop_chunk(ring.count, 16)
+        assert np.asarray(batch.t_a)[np.asarray(valid)].tolist() == \
+            [r.t_a for r in _LIVE]
+        return
+    # the reference: the eager path (clean), or ample capacity (growth)
+    ref = _drain_session(donate=False, **(
+        small if case == "clean" else dict(capacity=256,
+                                           pending_capacity=256)))
+    ref_res = [ref.offer(reqs) for reqs in _BURST]
+    for r, e in zip(res, ref_res):
+        np.testing.assert_array_equal(r.valid, e.valid)
+        for got, want in zip(jax.tree_util.tree_leaves(
+                (r.decision, r.batch)), jax.tree_util.tree_leaves(
+                (e.decision, e.batch))):
+            np.testing.assert_array_equal(np.asarray(got),
+                                          np.asarray(want))
+    assert m["accepted"] == ref.metrics()["accepted"] == 160
 
 
 # ---------------------------------------------------------------------------

@@ -218,8 +218,8 @@ def test_service_spans_nest_as_documented(tmp_path):
     spans = _host_spans(str(tmp_path))
     names = [n for n, _, _ in spans]
     for want in ("repro.offer", "repro.offer.stage", "repro.offer.dispatch",
-                 "repro.drain", "repro.drain.sync", "repro.drain.concat",
-                 "repro.restore"):
+                 "repro.offer.settle", "repro.drain", "repro.drain.sync",
+                 "repro.drain.concat", "repro.restore"):
         assert want in names, want
     assert "repro.drain.replay" not in names     # no overflow
 
@@ -229,6 +229,8 @@ def test_service_spans_nest_as_documented(tmp_path):
 
     assert not inside("repro.offer.stage", "repro.offer")
     assert not inside("repro.offer.dispatch", "repro.offer")
+    # the pipelined offer queues its own settling
+    assert not inside("repro.offer.settle", "repro.offer")
     assert not inside("repro.drain.sync", "repro.drain")
     assert not inside("repro.drain.concat", "repro.drain")
     chunks = -(-(len(jobs) - CHUNK) // CHUNK)
