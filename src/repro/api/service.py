@@ -201,12 +201,15 @@ def _check_demands(rspec, reqs) -> None:
 def _work_counters(s) -> Dict[str, jax.Array]:
     """The admit searches' index and kernel counters of a state, summed
     over its lanes: ``early_rejects`` (real requests the index rejected
-    whole), ``search_tiles`` (candidate tiles the kernel covered) and
-    ``search_tiles_skipped`` (those without a live candidate)."""
+    whole), ``search_tiles`` (candidate tiles the kernel covered),
+    ``search_tiles_skipped`` (those without a live candidate) and
+    ``search_pe_blocks`` (the PE blocks the kernel ran for its live
+    tiles)."""
     tiles = jnp.sum(s.n_search_tiles)
     return dict(early_rejects=jnp.sum(s.n_early_rejects),
                 search_tiles=tiles,
-                search_tiles_skipped=tiles - jnp.sum(s.n_search_tiles_run))
+                search_tiles_skipped=tiles - jnp.sum(s.n_search_tiles_run),
+                search_pe_blocks=jnp.sum(s.n_search_pe_blocks))
 
 
 # chunks a pipelined offer joins per group while its scans still run
@@ -392,8 +395,9 @@ class Session:
         searches run the Pallas kernel, ``"jnp"`` when they run the
         jnp reference (``use_kernel=False``, or a timeline beyond the
         kernel's budget), and the admit searches' work counters
-        ``early_rejects``, ``search_tiles`` and ``search_tiles_skipped``
-        (DESIGN.md §13), which rewind with :meth:`restore`.
+        ``early_rejects``, ``search_tiles``, ``search_tiles_skipped``
+        and ``search_pe_blocks`` (DESIGN.md §13), which rewind with
+        :meth:`restore`.
         ``settled_ahead`` counts pipelined offers whose results,
         prepared while their chunks ran, were installed unchanged;
         ``settled_after_replay`` those concatenated again after a

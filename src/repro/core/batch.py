@@ -745,7 +745,8 @@ def _no_displace(state: SchedulerState, req: RequestBatch,
         found=jnp.asarray(False), t_s=zero, t_e=zero,
         pe_mask=jnp.zeros((state.tl.words,), jnp.uint32),
         n_free=zero, t_begin=zero, t_end=zero,
-        early_reject=jnp.asarray(False), tiles=zero, tiles_run=zero)
+        early_reject=jnp.asarray(False), tiles=zero, tiles_run=zero,
+        pe_blocks=zero)
 
 
 def _displace(state: SchedulerState, req: RequestBatch,
@@ -969,7 +970,9 @@ def _admit_impl(state: SchedulerState, req: RequestBatch,
             n_search_tiles=state.n_search_tiles
             + jnp.where(counted, res.tiles, 0),
             n_search_tiles_run=state.n_search_tiles_run
-            + jnp.where(counted, res.tiles_run, 0))
+            + jnp.where(counted, res.tiles_run, 0),
+            n_search_pe_blocks=state.n_search_pe_blocks
+            + jnp.where(counted, res.pe_blocks, 0))
     need_add = jnp.asarray(True)
     with jax.named_scope("admit.displace"):
         if backfilling:

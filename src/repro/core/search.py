@@ -41,6 +41,7 @@ class SearchResult(NamedTuple):
     early_reject: jax.Array  # bool: the index's summary_reject fired
     tiles: jax.Array      # int32 candidate tiles the kernel covered
     tiles_run: jax.Array  # int32 of those, tiles with a live candidate
+    pe_blocks: jax.Array  # int32 (tile, PE block) steps the kernel ran
 
 class Rectangles(NamedTuple):
     """Per-candidate maximum availability rectangles.
@@ -385,7 +386,7 @@ def search(
                     t_begin=rects.t_begin[0],
                     t_end=rects.t_end[0],
                     early_reject=jnp.asarray(True), tiles=jnp.int32(0),
-                    tiles_run=jnp.int32(0),
+                    tiles_run=jnp.int32(0), pe_blocks=jnp.int32(0),
                 )
 
         def _full(_):
@@ -449,7 +450,7 @@ def _search_full(
         n_free, t_begin, t_end = sel["n_free"], sel["t_begin"], \
             sel["t_end"]
         work = dict(early_reject=jnp.asarray(False), tiles=sel["tiles"],
-                    tiles_run=sel["tiles_run"])
+                    tiles_run=sel["tiles_run"], pe_blocks=sel["pe_blocks"])
     else:
         # jnp reference path — also the fallback when search_select
         # returned None (shape beyond the kernel VMEM budget; the
@@ -471,7 +472,8 @@ def _search_full(
             n_free, t_begin, t_end = rects.n_free[best], \
                 rects.t_begin[best], rects.t_end[best]
         work = dict(early_reject=jnp.asarray(False),
-                    tiles=jnp.int32(0), tiles_run=jnp.int32(0))
+                    tiles=jnp.int32(0), tiles_run=jnp.int32(0),
+                    pe_blocks=jnp.int32(0))
     with jax.named_scope("admit.search.mask"):
         if rspec is None:
             pe_mask = _winning_pe_mask(tl, t_s, t_du, n_req, n_pe)

@@ -171,6 +171,8 @@ class SchedulerState(NamedTuple):
     n_search_tiles_run: jax.Array  # int32 scalar: of those, tiles
     #                                with a live candidate (the rest
     #                                were skipped)
+    n_search_pe_blocks: jax.Array  # int32 scalar: (tile, PE block)
+    #                                steps the kernel ran
     overflow: jax.Array    # bool scalar
     hw_records: jax.Array  # int32 scalar: max records any update needed
     hw_pending: jax.Array  # int32 scalar: max pending slots needed
@@ -272,6 +274,7 @@ def init_state(capacity: int, n_pe: int,
         n_early_rejects=jnp.int32(0),
         n_search_tiles=jnp.int32(0),
         n_search_tiles_run=jnp.int32(0),
+        n_search_pe_blocks=jnp.int32(0),
         overflow=jnp.asarray(False),
         hw_records=jnp.int32(0),
         hw_pending=jnp.int32(0),

@@ -8,12 +8,18 @@ into two MXU contractions per candidate tile (DESIGN.md §2):
     busy[Pt, pe]    = overlap[Pt, S] @ occ_bits[S, pe]      (window union)
     blocking[Pt, S] = free[Pt, pe]   @ occ_bits[S, pe]^T    (rect expansion)
 
-Grid: one program per tile of ``Pt`` candidate start times.  The
-occupancy matrix (the shared operand) is mapped to a single grid-
-invariant VMEM block, so it is DMA'd from HBM once and reused by every
-candidate tile — the TPU analogue of the paper's "organise availability
-for efficient search".  All comparisons stay in exact int32; only the
-0/1 contraction operands are f32 (counts < 2**24, exact).
+Grid (scalar kernels): one program per (tile of ``Pt`` candidate start
+times, PE block).  The occupancy arrives packed, uint32 ``[S, W]``, one
+block of words per step (:func:`pe_blocks`: a machine of up to 4096
+PEs is a single block, DMA'd from HBM once and expanded once for every
+candidate tile — the TPU analogue of the paper's "organise
+availability for efficient search"; a wider one takes blocks of 4096
+PEs, the last one partial).  Each step expands its block's bits in
+VMEM; both contractions separate over blocks (the free counts sum, a
+slot blocks if it occupies a free PE of any block), so a tile
+accumulates them and finishes on its last block.  All comparisons stay
+in exact int32; only the 0/1 contraction operands are f32 (counts <
+2**24, exact).
 
 Occupancy awareness (DESIGN.md §7, §12): the candidate array arrives
 deduplicated and compacted (live starts first, ``T_INF`` tail — see
@@ -34,10 +40,13 @@ accumulator across the sequential grid, so only one 8-lane result row
 leaves the kernel — the per-candidate ``[P]`` vectors (and the
 ``[Pt, S]`` blocking matrix) never round-trip through HBM.
 
-VMEM budget per program (defaults Pt=128, S<=1024, n_pe<=2048):
-occ_bits f32[S, pe] = 8 MiB worst case + tiles ~1.5 MiB < 16 MiB.
-Beyond these bounds ops.py runs the pure-jnp path instead
-(``ops.fits``), and sessions report it as their ``search_path``.
+VMEM budget per program (defaults Pt=128): one PE block's expanded
+records, ``S * 32 * block_words <= 2**21`` elements (``ops.fits``): a
+4096-PE block at up to S=512 records, a 1024-PE machine up to S=2048.
+The ``_mr`` kernels keep the f32 bit planes of the whole bit axis in one
+block, ``S * bits <= 2**21``.  Beyond the budget ops.py runs the
+pure-jnp path instead, and sessions report it as their
+``search_path``.
 """
 from __future__ import annotations
 
@@ -53,8 +62,13 @@ from repro.core.types import T_INF
 
 # Tile of candidate start times evaluated by one program instance.
 DEFAULT_PT = 128
-# TPU lane width; S and n_pe are padded to multiples of this.
+# TPU lane width; S (and the _mr kernels' n_pe) are padded to
+# multiples of this.
 _LANE = 128
+_WORD = 32
+# The widest PE block of the scalar kernels, in packed words (4096
+# PEs); a wider machine is tiled over several blocks.
+BLOCK_WORDS = 128
 _BIG = jnp.iinfo(jnp.int32).max
 
 
@@ -73,23 +87,156 @@ def _interpret_mode() -> bool:
         f"compiled kernel for platform {platform!r}")
 
 
-def _tile_rects(a, b, times, nxt, occ):
-    """The two MXU contractions + rectangle bounds for one tile."""
-    ov = ((times[None, :] < b[:, None]) &
-          (nxt[None, :] > a[:, None])).astype(jnp.float32)     # [Pt, S]
-    busy = jax.lax.dot(ov, occ,
-                       preferred_element_type=jnp.float32)     # [Pt, pe]
-    free = (busy < 0.5)
-    nfree = jnp.sum(free.astype(jnp.int32), axis=1)
-    blocking = jax.lax.dot_general(
-        free.astype(jnp.float32), occ,
-        dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) > 0.5              # [Pt, S]
+def pe_blocks(words: int) -> Tuple[int, int]:
+    """``(block_words, n_blocks)``: how the scalar kernels tile a
+    timeline of ``words`` packed occupancy words over its PEs.
+
+    A machine of at most :data:`BLOCK_WORDS` words is one block of its
+    word count rounded up to a power of two (at least 4, so that the
+    expanded block fills whole 128-lane rows); a wider one takes
+    blocks of ``BLOCK_WORDS`` words, the last one partial.
+    """
+    if words <= BLOCK_WORDS:
+        return max(4, 1 << (words - 1).bit_length()), 1
+    return BLOCK_WORDS, -(-words // BLOCK_WORDS)
+
+
+def _expand_bits(w, j, *, block_words, n_words):
+    """f32 0/1 ``[S, 32 * block_words]``: the bits of PE block ``j``'s
+    packed words ``w`` (int32 ``[S, block_words]``).
+
+    The order of PEs inside a block is free (counts and "any" do not
+    depend on it), so the expansion is bit-major and lane-dense: the
+    words are repeated across one 128-lane row, ``rep`` times, and
+    group ``g`` of 128 lanes holds bit ``g * rep + lane // block_words``
+    of word ``lane % block_words``.  Words past the timeline's last
+    (a partial last block) read as empty.
+    """
+    rep = _LANE // block_words
+    x = w if rep == 1 else jnp.concatenate([w] * rep, axis=1)  # [S, 128]
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    word = lane % block_words
+    if n_words % block_words:
+        x = jnp.where(j * block_words + word < n_words, x, 0)
+    shift = lane // block_words
+    planes = [jax.lax.shift_right_logical(x, shift + g * rep) & 1
+              for g in range(_WORD // rep)]
+    bits = planes[0] if len(planes) == 1 else jnp.concatenate(planes, 1)
+    return bits.astype(jnp.float32)
+
+
+def _bounds(a, b, times, nxt, blocking):
+    """Rectangle bounds of a tile from its blocking slots ``[Pt, S]``."""
     left = blocking & (nxt[None, :] <= a[:, None])
     tb = jnp.max(jnp.where(left, nxt[None, :], -T_INF), axis=1)
     right = blocking & (times[None, :] >= b[:, None])
     te = jnp.min(jnp.where(right, times[None, :], T_INF), axis=1)
-    return nfree, tb, te
+    return tb, te
+
+
+def _blocked_rects(live, a_ref, b_ref, times_ref, nxt_ref, occ_ref,
+                   bits_ref, acc_refs, finish, *, n_blocks, block_words,
+                   n_words):
+    """One (candidate tile, PE block) step of the scalar kernels: the
+    two MXU contractions of DESIGN.md §2 on this block's PEs.
+
+    Both separate over PE blocks: ``n_free`` sums, and a slot blocks
+    the tile's rectangles iff it occupies a free PE of any block, so
+    the blocking counts sum too.  On the tile's last block
+    ``finish(n_free_raw, t_begin_raw, t_end_raw)`` runs with the
+    tile's totals.  The 0/1 operands and the counts are f32 (counts <
+    2**24, exact).
+    """
+    i, j = pl.program_id(0), pl.program_id(1)
+
+    # a single block is the same for every tile: expand it once
+    @pl.when((i == 0) if n_blocks == 1 else live)
+    def _():
+        bits_ref[...] = _expand_bits(occ_ref[...], j,
+                                     block_words=block_words,
+                                     n_words=n_words)
+
+    @pl.when(live)
+    def _():
+        a, b = a_ref[:, 0], b_ref[:, 0]
+        times, nxt = times_ref[0, :], nxt_ref[0, :]
+        bits = bits_ref[...]
+        ov = ((times[None, :] < b[:, None]) &
+              (nxt[None, :] > a[:, None])).astype(jnp.float32)  # [Pt, S]
+        busy = jax.lax.dot(ov, bits,
+                           preferred_element_type=jnp.float32)  # [Pt, pe]
+        free = busy < 0.5
+        nfree = jnp.sum(free.astype(jnp.int32), axis=1)
+        blocking = jax.lax.dot_general(
+            free.astype(jnp.float32), bits,
+            dimension_numbers=(((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)                 # [Pt, S]
+        if n_blocks == 1:
+            finish(nfree, *_bounds(a, b, times, nxt, blocking > 0.5))
+            return
+        nfree_acc, blocking_acc = acc_refs
+
+        @pl.when(j == 0)
+        def _():
+            nfree_acc[:, 0] = nfree
+            blocking_acc[...] = blocking
+
+        @pl.when(j > 0)
+        def _():
+            nfree_acc[:, 0] += nfree
+            blocking_acc[...] += blocking
+
+        @pl.when(j == n_blocks - 1)
+        def _():
+            finish(nfree_acc[:, 0], *_bounds(
+                a, b, times, nxt, blocking_acc[...] > 0.5))
+
+
+def _blocked_call(kernel, prefetch, per_tile, times, nxt, occ, *, pt,
+                  out_specs, out_shape, interpret):
+    """A scalar kernel over the grid (candidate tiles, PE blocks).
+
+    ``occ`` is the packed timeline, uint32 ``[S, W]``, read one PE
+    block per step and expanded to bits in VMEM; ``per_tile`` are the
+    ``[P_pad, 1]`` candidate operands; the live counts per tile are the
+    last scalar-prefetch operand.  A dead tile keeps the PE block of
+    the step before it, so its steps load nothing.
+    """
+    S, W = occ.shape
+    assert S % _LANE == 0, S
+    block_words, n_blocks = pe_blocks(W)
+    if n_blocks == 1 and W < block_words:
+        occ = jnp.pad(occ, ((0, 0), (0, block_words - W)))
+    occ = jax.lax.bitcast_convert_type(occ, jnp.int32)
+    n_tiles = per_tile[0].shape[0] // pt
+
+    def occ_block(i, j, *prefetch):
+        if n_blocks == 1:
+            return 0, 0
+        return 0, jnp.where(prefetch[-1][i] > 0, j, n_blocks - 1)
+
+    scratch = [pltpu.VMEM((S, _WORD * block_words), jnp.float32)]
+    if n_blocks > 1:
+        scratch += [pltpu.VMEM((pt, 1), jnp.int32),
+                    pltpu.VMEM((pt, S), jnp.float32)]
+    tile_spec = pl.BlockSpec((pt, 1), lambda i, j, *_: (i, 0))
+    row_spec = pl.BlockSpec((1, S), lambda i, j, *_: (0, 0))
+    return pl.pallas_call(
+        functools.partial(kernel, pt=pt, n_blocks=n_blocks,
+                          block_words=block_words,
+                          n_words=occ.shape[1]),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(prefetch),
+            grid=(n_tiles, n_blocks),
+            in_specs=[tile_spec] * len(per_tile) + [
+                row_spec, row_spec,
+                pl.BlockSpec((S, block_words), occ_block)],
+            out_specs=out_specs,
+            scratch_shapes=scratch,
+        ),
+        out_shape=out_shape,
+        interpret=_interpret_mode() if interpret is None else interpret,
+    )(*prefetch, *per_tile, times[None, :], nxt[None, :], occ)
 
 
 def _tile_rects_mr(a, b, times, nxt, occ, psel):
@@ -125,38 +272,40 @@ def _tile_live(live: jax.Array, P_pad: int, pt: int) -> jax.Array:
     return jnp.sum(lv.reshape(P_pad // pt, pt), axis=1)
 
 
-def tile_counts(live: jax.Array, pt: int = DEFAULT_PT) -> dict:
+def tile_counts(live: jax.Array, pt: int = DEFAULT_PT,
+                n_blocks: int = 1) -> dict:
     """``tiles``: the candidate tiles a kernel grid over ``live``
     covers; ``tiles_run``: those with a live candidate (the others
-    skip their MXU work)."""
+    skip their MXU work); ``pe_blocks``: the (tile, PE block) steps
+    that ran, ``n_blocks`` per live tile."""
     P_pad = -(-live.shape[-1] // pt) * pt
     tlive = _tile_live(live, P_pad, pt)
-    return dict(tiles=jnp.int32(P_pad // pt),
-                tiles_run=jnp.sum(tlive > 0, dtype=jnp.int32))
+    run = jnp.sum(tlive > 0, dtype=jnp.int32)
+    return dict(tiles=jnp.int32(P_pad // pt), tiles_run=run,
+                pe_blocks=run * n_blocks)
 
 
 def _availscan_kernel(tlive_ref, a_ref, b_ref, times_ref, nxt_ref,
-                      occ_ref, nfree_ref, tb_ref, te_ref, *, pt):
-    i = pl.program_id(0)
-    live = tlive_ref[i] > 0
+                      occ_ref, nfree_ref, tb_ref, te_ref, bits_ref,
+                      *acc_refs, pt, **blocks):
+    live = tlive_ref[pl.program_id(0)] > 0
 
-    @pl.when(live)
-    def _():
-        nfree, tb, te = _tile_rects(
-            a_ref[:, 0], b_ref[:, 0], times_ref[0, :], nxt_ref[0, :],
-            occ_ref[...])
+    def finish(nfree, tb, te):
         nfree_ref[:, 0] = nfree
         tb_ref[:, 0] = tb
         te_ref[:, 0] = te
+
+    _blocked_rects(live, a_ref, b_ref, times_ref, nxt_ref, occ_ref,
+                   bits_ref, acc_refs, finish, **blocks)
 
     @pl.when(~live)
     def _():
         # all-padding tile: sentinel outputs, no MXU work.  The ops.py
         # wrapper masks every invalid candidate to the reference
         # sentinels afterwards, so these values are never observed.
-        nfree_ref[:, 0] = jnp.zeros((pt,), jnp.int32)
-        tb_ref[:, 0] = jnp.full((pt,), -T_INF, jnp.int32)
-        te_ref[:, 0] = jnp.full((pt,), T_INF, jnp.int32)
+        finish(jnp.zeros((pt,), jnp.int32),
+               jnp.full((pt,), -T_INF, jnp.int32),
+               jnp.full((pt,), T_INF, jnp.int32))
 
 
 def _pad_to(x: jax.Array, size: int, fill) -> jax.Array:
@@ -170,7 +319,7 @@ def _pad_to(x: jax.Array, size: int, fill) -> jax.Array:
 @functools.partial(
     jax.jit, static_argnames=("pt", "interpret"))
 def availscan(
-    occ_bits: jax.Array,   # f32[S, n_pe_padded] 0/1 occupancy
+    occ: jax.Array,        # uint32[S, W] packed occupancy words
     times: jax.Array,      # i32[S]
     nxt: jax.Array,        # i32[S]
     a: jax.Array,          # i32[P] window starts (overflow-clamped)
@@ -181,49 +330,27 @@ def availscan(
     pt: int = DEFAULT_PT,
     interpret: Optional[bool] = None,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Tiled scan over candidates, skipping all-dead tiles.
+    """Tiled scan over candidates and PE blocks, skipping dead tiles.
 
     Returns raw ``(n_free, t_begin_raw, t_end_raw)`` — ``n_free`` still
-    counts PE-axis padding (caller subtracts) and the bounds carry
-    ``-T_INF`` / ``T_INF`` sentinels when unblocked (caller clamps).
-    ``live`` reduces to per-tile counts in the scalar-prefetch operand:
-    tiles with no live candidate (all padding or all summary-pruned)
-    skip both contractions.
+    counts the PE blocks' padding bits (``32 * block_words * n_blocks
+    - n_pe``, caller subtracts) and the bounds carry ``-T_INF`` /
+    ``T_INF`` sentinels when unblocked (caller clamps).  ``live``
+    reduces to per-tile counts in the scalar-prefetch operand: tiles
+    with no live candidate (all padding or all summary-pruned) skip
+    both contractions.
     """
-    S, n_pe_p = occ_bits.shape
-    assert S % _LANE == 0 and n_pe_p % _LANE == 0, (S, n_pe_p)
     P = a.shape[0]
     P_pad = -(-P // pt) * pt
-    a_p = _pad_to(a, P_pad, T_INF - 1)[:, None]
-    b_p = _pad_to(b, P_pad, T_INF)[:, None]
     tlive = _tile_live(live, P_pad, pt)
-    grid = (P_pad // pt,)
-    nfree, tb, te = pl.pallas_call(
-        functools.partial(_availscan_kernel, pt=pt),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((pt, 1), lambda i, s: (i, 0)),      # a
-                pl.BlockSpec((pt, 1), lambda i, s: (i, 0)),      # b
-                pl.BlockSpec((1, S), lambda i, s: (0, 0)),       # times
-                pl.BlockSpec((1, S), lambda i, s: (0, 0)),       # nxt
-                pl.BlockSpec((S, n_pe_p), lambda i, s: (0, 0)),  # occ
-            ],
-            out_specs=[
-                pl.BlockSpec((pt, 1), lambda i, s: (i, 0)),
-                pl.BlockSpec((pt, 1), lambda i, s: (i, 0)),
-                pl.BlockSpec((pt, 1), lambda i, s: (i, 0)),
-            ],
-        ),
-        out_shape=[
-            jax.ShapeDtypeStruct((P_pad, 1), jnp.int32),
-            jax.ShapeDtypeStruct((P_pad, 1), jnp.int32),
-            jax.ShapeDtypeStruct((P_pad, 1), jnp.int32),
-        ],
-        interpret=_interpret_mode() if interpret is None else interpret,
-    )(tlive, a_p, b_p,
-      times[None, :], nxt[None, :], occ_bits)
+    col = jax.ShapeDtypeStruct((P_pad, 1), jnp.int32)
+    out_spec = pl.BlockSpec((pt, 1), lambda i, j, t: (i, 0))
+    nfree, tb, te = _blocked_call(
+        _availscan_kernel, [tlive],
+        [_pad_to(a, P_pad, T_INF - 1)[:, None],
+         _pad_to(b, P_pad, T_INF)[:, None]],
+        times, nxt, occ, pt=pt, out_specs=[out_spec] * 3,
+        out_shape=[col] * 3, interpret=interpret)
     return nfree[:P, 0], tb[:P, 0], te[:P, 0]
 
 
@@ -331,14 +458,14 @@ def _integer_keys_tile(policy_id, n_free, duration):
 
 def _availscan_select_kernel(scal_ref, tlive_ref, starts_ref, a_ref,
                              b_ref, times_ref, nxt_ref, occ_ref,
-                             acc_ref, *, pt):
+                             acc_ref, bits_ref, *acc_refs, pt, **blocks):
     i = pl.program_id(0)
     policy_id = scal_ref[0]
     n_req = scal_ref[1]
     t_now = scal_ref[2]
     pad_corr = scal_ref[3]
 
-    @pl.when(i == 0)
+    @pl.when((i == 0) & (pl.program_id(1) == 0))
     def _():
         # lexicographic +inf on the four comparison lanes: no tile
         # has contributed yet (built from iota — pallas kernels may
@@ -346,13 +473,9 @@ def _availscan_select_kernel(scal_ref, tlive_ref, starts_ref, a_ref,
         lane = jax.lax.iota(jnp.int32, 8)
         acc_ref[0, :] = jnp.where(lane < 4, _BIG, 0)
 
-    @pl.when(tlive_ref[i] > 0)
-    def _():
+    def finish(nfree_raw, tb_raw, te_raw):
         starts = starts_ref[:, 0]
         a = a_ref[:, 0]
-        nfree_raw, tb_raw, te_raw = _tile_rects(
-            a, b_ref[:, 0], times_ref[0, :], nxt_ref[0, :],
-            occ_ref[...])
         valid = starts < T_INF
         # the exact post-processing of the ops.py wrapper / jnp ref
         zero = jnp.zeros((pt,), jnp.int32)
@@ -393,11 +516,14 @@ def _availscan_select_kernel(scal_ref, tlive_ref, starts_ref, a_ref,
                     (row[2] == acc[2]) & (row[3] < acc[3]))))))
         acc_ref[0, :] = jnp.where(less, row, acc)
 
+    _blocked_rects(tlive_ref[i] > 0, a_ref, b_ref, times_ref, nxt_ref,
+                   occ_ref, bits_ref, acc_refs, finish, **blocks)
+
 
 @functools.partial(
     jax.jit, static_argnames=("pt", "interpret"))
 def availscan_select(
-    occ_bits: jax.Array,   # f32[S, n_pe_padded] 0/1 occupancy
+    occ: jax.Array,        # uint32[S, W] packed occupancy words
     times: jax.Array,      # i32[S]
     nxt: jax.Array,        # i32[S]
     starts: jax.Array,     # i32[P] candidate starts (T_INF padded)
@@ -415,40 +541,25 @@ def availscan_select(
     t_end, feasible`` of the winning candidate — post-processed values
     (pad-corrected ``n_free``, clamped ``t_begin``), bit-identical to
     the jnp ``availability_rectangles`` + ``policies.select`` chain.
-    Tiles whose per-tile live count is zero are skipped entirely; on
-    compacted (prefix-live) inputs this degenerates to the old
-    ``i*pt < n_live`` prefix skip, and index pruning punches holes
-    without ever skipping a tile that still holds a live candidate.
+    The grid runs every PE block (:func:`pe_blocks`) of each candidate
+    tile and selects on the tile's last block.  Tiles whose per-tile
+    live count is zero are skipped entirely; on compacted
+    (prefix-live) inputs this degenerates to the old ``i*pt < n_live``
+    prefix skip, and index pruning punches holes without ever skipping
+    a tile that still holds a live candidate.
     """
-    S, n_pe_p = occ_bits.shape
-    assert S % _LANE == 0 and n_pe_p % _LANE == 0, (S, n_pe_p)
     P = a.shape[0]
     P_pad = -(-P // pt) * pt
     tlive = _tile_live(live, P_pad, pt)
-    starts_p = _pad_to(starts, P_pad, T_INF)[:, None]
-    a_p = _pad_to(a, P_pad, T_INF - 1)[:, None]
-    b_p = _pad_to(b, P_pad, T_INF)[:, None]
-    grid = (P_pad // pt,)
-    acc = pl.pallas_call(
-        functools.partial(_availscan_select_kernel, pt=pt),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((pt, 1), lambda i, s, t: (i, 0)),   # starts
-                pl.BlockSpec((pt, 1), lambda i, s, t: (i, 0)),   # a
-                pl.BlockSpec((pt, 1), lambda i, s, t: (i, 0)),   # b
-                pl.BlockSpec((1, S), lambda i, s, t: (0, 0)),    # times
-                pl.BlockSpec((1, S), lambda i, s, t: (0, 0)),    # nxt
-                pl.BlockSpec((S, n_pe_p),
-                             lambda i, s, t: (0, 0)),            # occ
-            ],
-            out_specs=pl.BlockSpec((1, 8), lambda i, s, t: (0, 0)),
-        ),
+    acc = _blocked_call(
+        _availscan_select_kernel, [scalars.astype(jnp.int32), tlive],
+        [_pad_to(starts, P_pad, T_INF)[:, None],
+         _pad_to(a, P_pad, T_INF - 1)[:, None],
+         _pad_to(b, P_pad, T_INF)[:, None]],
+        times, nxt, occ, pt=pt,
+        out_specs=pl.BlockSpec((1, 8), lambda i, j, s, t: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((1, 8), jnp.int32),
-        interpret=_interpret_mode() if interpret is None else interpret,
-    )(scalars.astype(jnp.int32), tlive, starts_p, a_p, b_p,
-      times[None, :], nxt[None, :], occ_bits)
+        interpret=interpret)
     return acc[0]
 
 

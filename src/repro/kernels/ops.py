@@ -1,9 +1,12 @@
 """Jit'd public wrappers around the availscan Pallas kernels.
 
-Prepares the dense operands from a :class:`~repro.core.timeline.Timeline`
-(bit-expansion, lane padding), invokes the kernel, and post-processes
-the raw tile outputs back into the exact semantics of the pure-jnp
-reference (:func:`repro.core.search.availability_rectangles`).
+Prepares the operands from a :class:`~repro.core.timeline.Timeline`
+(the packed occupancy words for the scalar kernels, which expand them
+in VMEM one PE block at a time; bit-expanded planes for the
+multi-resource kernels; lane padding), invokes the kernel, and
+post-processes the raw tile outputs back into the exact semantics of
+the pure-jnp reference
+(:func:`repro.core.search.availability_rectangles`).
 
 Occupancy awareness (DESIGN.md §7, §12): the live candidate mask — the
 non-``T_INF`` entries of the deduplicated, compacted (and possibly
@@ -17,7 +20,7 @@ produces, keeping the two paths element-identical.
 kernel (the per-candidate vectors never leave the kernel); the
 ``search`` hot path uses it on the kernel path.
 
-On shapes beyond the kernel's single-block VMEM budget (:func:`fits`)
+On shapes beyond the kernels' per-program VMEM budget (:func:`fits`)
 the wrappers run the reference path instead (``search_select`` returns
 ``None`` and the caller runs the jnp chain); sessions report which path
 their shape takes as the ``search_path`` metric.
@@ -36,7 +39,9 @@ from repro.core.timeline import Timeline
 from repro.core.types import T_INF
 from repro.kernels import availscan as _k
 
-# Single-block VMEM budget: S * n_pe f32 occupancy <= 8 MiB.
+# VMEM budget of one program: the S records of one PE block (the
+# scalar kernels) or of the whole bit axis (the _mr kernels) as 0/1
+# elements, S * bits <= 2**21.
 _MAX_OCC_ELEMS = 2 * 1024 * 1024
 
 
@@ -47,33 +52,42 @@ def _round_up(x: int, m: int) -> int:
 def fits(capacity: int, n_pe: int, rspec=None) -> bool:
     """Whether a timeline of this shape is within the kernels' budget.
 
-    Beyond it the wrappers below run the jnp reference instead; the
-    choice is static (shape only), so the service reports it as the
-    session's ``search_path`` metric.
+    The scalar kernels tile the PE axis, so only one PE block's records
+    count (``kernels/availscan.pe_blocks``); the multi-resource kernels
+    hold the whole bit axis in one block.  Beyond the budget the
+    wrappers below run the jnp reference instead; the choice is static
+    (shape only), so the service reports it as the session's
+    ``search_path`` metric.
     """
-    if rspec is not None:
-        if rspec.R > _k._LANE:
-            return False
-        n_pe = rspec.total_bits
-    return (_round_up(max(capacity, _k._LANE), _k._LANE)
-            * _round_up(max(n_pe, _k._LANE), _k._LANE)
+    s_pad = _round_up(max(capacity, _k._LANE), _k._LANE)
+    if rspec is None:
+        block_words = _k.pe_blocks(tl_lib.n_words(n_pe))[0]
+        return s_pad * 32 * block_words <= _MAX_OCC_ELEMS
+    if rspec.R > _k._LANE:
+        return False
+    return (s_pad * _round_up(max(rspec.total_bits, _k._LANE), _k._LANE)
             <= _MAX_OCC_ELEMS)
 
 
 def _padded_operands(tl: Timeline, n_pe: int):
-    """Lane-padded dense operands shared by both kernel entries."""
+    """The scalar kernels' operands: the packed occupancy words, times
+    and next times, with the records padded to the lane width, and the
+    padding bits of the PE blocks (``32 * block_words * n_blocks -
+    n_pe``), which the kernels count as free.  No bit is expanded
+    here: the kernels expand one PE block at a time in VMEM."""
     if not fits(tl.capacity, n_pe):
         return None
     S = tl.capacity
     S_pad = _round_up(max(S, _k._LANE), _k._LANE)
-    n_pe_pad = _round_up(max(n_pe, _k._LANE), _k._LANE)
-    occ_bits = tl_lib.unpack_bits(tl.occ, n_pe).astype(jnp.float32)
-    occ_bits = jnp.pad(
-        occ_bits, ((0, S_pad - S), (0, n_pe_pad - n_pe)))
+    occ = tl.occ
+    if S_pad > S:
+        occ = jnp.pad(occ, ((0, S_pad - S), (0, 0)))
     times = jnp.pad(tl.times, (0, S_pad - S), constant_values=T_INF)
     nxt = jnp.pad(tl_lib.next_times(tl), (0, S_pad - S),
                   constant_values=T_INF)
-    return occ_bits, times, nxt, n_pe_pad
+    block_words, n_blocks = _k.pe_blocks(tl.words)
+    pad_bits = 32 * block_words * n_blocks - n_pe
+    return occ, times, nxt, pad_bits, n_blocks
 
 
 def _padded_operands_mr(tl: Timeline, rspec,
@@ -141,17 +155,16 @@ def availability_rectangles(
     if ops is None:
         return search_lib.availability_rectangles(
             tl, starts, t_du, t_now, n_pe)
-    occ_bits, times, nxt, n_pe_pad = ops
+    occ, times, nxt, pad_bits, _ = ops
 
     valid = starts < T_INF
     a = jnp.minimum(starts, T_INF - t_du)   # avoid int32 overflow
     b = a + t_du
 
-    nfree_raw, tb_raw, te_raw = _k.availscan(
-        occ_bits, times, nxt, a, b, valid)
+    nfree_raw, tb_raw, te_raw = _k.availscan(occ, times, nxt, a, b, valid)
 
     zero = jnp.int32(0)
-    n_free = nfree_raw - (n_pe_pad - n_pe)   # padded PE bits never busy
+    n_free = nfree_raw - pad_bits   # padding PE bits never busy
     t_begin = jnp.minimum(jnp.maximum(tb_raw, t_now), a)
     # invalid candidates (skipped tiles included) take the reference
     # sentinels, keeping kernel and jnp paths element-identical
@@ -206,15 +219,16 @@ def search_select(
     ops = _padded_operands(tl, n_pe)
     if ops is None:
         return None
-    occ_bits, times, nxt, n_pe_pad = ops
+    occ, times, nxt, pad_bits, n_blocks = ops
     live = starts < T_INF
     a = jnp.minimum(starts, T_INF - t_du)
     b = a + t_du
     scalars = jnp.stack([
         jnp.asarray(policy_id, jnp.int32),
         jnp.asarray(n_req, jnp.int32), jnp.asarray(t_now, jnp.int32),
-        jnp.int32(n_pe_pad - n_pe)])
+        jnp.int32(pad_bits)])
     acc = _k.availscan_select(
-        occ_bits, times, nxt, starts, a, b, scalars, live)
+        occ, times, nxt, starts, a, b, scalars, live)
     return dict(found=acc[7] > 0, best=acc[3], n_free=acc[4],
-                t_begin=acc[5], t_end=acc[6], **_k.tile_counts(live))
+                t_begin=acc[5], t_end=acc[6],
+                **_k.tile_counts(live, n_blocks=n_blocks))

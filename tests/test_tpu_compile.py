@@ -3,9 +3,11 @@
 The TPU compiler is installed with JAX and compiles for a topology that
 is described rather than present.  These tests compile the four Pallas
 ``availscan*`` kernels at the paper's width (1024 PEs) and the kernel's
-budget edge (S = 2048 timeline records), and one ``admit_stream`` chunk
-step (1024 PEs, capacity 1024, 64 requests) with and without the
-kernel.  Nothing runs; what the chip's compiler would refuse (tiling,
+budget edge (S = 2048 timeline records), ``availscan_select`` at the
+CEA Curie thin-node width (80,640 PEs, twenty PE blocks, capacity 128),
+and one ``admit_stream`` chunk step (64 requests) with and without the
+kernel, at 1024 PEs and capacity 1024 and, on the kernel, at the Curie
+shape.  Nothing runs; what the chip's compiler would refuse (tiling,
 fast-memory limits, lowering) fails here.
 
 The topology is described inside a fixture, never at import: only one
@@ -13,6 +15,7 @@ process at a time may load the TPU library, and every test worker
 imports this file.
 """
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -27,8 +30,9 @@ from repro.kernels import availscan as _k
 
 N_PE = 1024
 S = 2048                 # kernel budget edge at 1024 PEs (ops.fits)
-P = 2 * S + 2            # candidate starts of an S-record timeline
 CAPACITY, CHUNK = 1024, 64
+# the CEA Curie thin-node partition: 5,040 nodes x 16 cores
+CURIE_PE, CURIE_CAPACITY = 80640, 128
 
 
 @pytest.fixture(scope="module")
@@ -62,37 +66,51 @@ def _spec(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def _kernel_args(name, sh):
+def _kernel_args(name, sh, s=S, n_pe=N_PE):
     i32, f32 = jnp.int32, jnp.float32
-    vec = functools.partial(_spec, (P,), i32, sh)
-    rows = [_spec((S,), i32, sh), _spec((S,), i32, sh)]   # times, nxt
-    live = _spec((P,), jnp.bool_, sh)
+    p = 2 * s + 2            # candidate starts of an s-record timeline
+    vec = functools.partial(_spec, (p,), i32, sh)
+    rows = [_spec((s,), i32, sh), _spec((s,), i32, sh)]   # times, nxt
+    live = _spec((p,), jnp.bool_, sh)
+    words = _spec((s, tl_lib.n_words(n_pe)), jnp.uint32, sh)
     if name == "availscan":
-        return (_spec((S, N_PE), f32, sh), *rows, vec(), vec(), live), {}
+        return (words, *rows, vec(), vec(), live), {}
     if name == "availscan_select":
-        return (_spec((S, N_PE), f32, sh), *rows, vec(), vec(), vec(),
+        return (words, *rows, vec(), vec(), vec(),
                 _spec((4,), i32, sh), live), {}
     # multi-resource: two planes of 512 units share the 1024-bit axis
-    psel = _spec((N_PE, _k._LANE), f32, sh)
+    psel = _spec((n_pe, _k._LANE), f32, sh)
     if name == "availscan_mr":
-        return (_spec((S, N_PE), f32, sh), psel, *rows, vec(), vec(),
+        return (_spec((s, n_pe), f32, sh), psel, *rows, vec(), vec(),
                 live), {}
-    return (_spec((S, N_PE), f32, sh), psel, *rows, vec(), vec(), vec(),
+    return (_spec((s, n_pe), f32, sh), psel, *rows, vec(), vec(), vec(),
             _spec((4,), i32, sh), live), {"n_res": 2}
 
 
-@pytest.mark.parametrize("name", ["availscan", "availscan_select",
-                                  "availscan_mr", "availscan_select_mr"])
-def test_kernel_compiles_for_v5e(name, one_chip, no_persistent_cache):
-    args, static = _kernel_args(name, one_chip)
+@pytest.mark.parametrize("name,s,n_pe", [
+    pytest.param("availscan", S, N_PE, id="availscan"),
+    pytest.param("availscan_select", S, N_PE, id="availscan_select"),
+    pytest.param("availscan_mr", S, N_PE, id="availscan_mr"),
+    pytest.param("availscan_select_mr", S, N_PE,
+                 id="availscan_select_mr"),
+    pytest.param("availscan_select", CURIE_CAPACITY, CURIE_PE,
+                 id="availscan_select-curie"),
+])
+def test_kernel_compiles_for_v5e(name, s, n_pe, one_chip,
+                                 no_persistent_cache):
+    args, static = _kernel_args(name, one_chip, s, n_pe)
     fn = getattr(_k, name)
     compiled = fn.lower(*args, interpret=False, **static).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("use_kernel", [False, True],
-                         ids=["jnp", "kernel"])
-def test_admit_chunk_step_compiles_for_v5e(use_kernel, one_chip,
+@pytest.mark.parametrize("use_kernel,capacity,n_pe", [
+    pytest.param(False, CAPACITY, N_PE, id="jnp"),
+    pytest.param(True, CAPACITY, N_PE, id="kernel"),
+    pytest.param(True, CURIE_CAPACITY, CURIE_PE, id="kernel-curie"),
+])
+def test_admit_chunk_step_compiles_for_v5e(use_kernel, capacity, n_pe,
+                                           one_chip,
                                            no_persistent_cache,
                                            monkeypatch):
     # the program resolves interpret mode from the default backend,
@@ -102,7 +120,7 @@ def test_admit_chunk_step_compiles_for_v5e(use_kernel, one_chip,
     jax.clear_caches()
     tile = 16 if use_kernel else None
     state = jax.eval_shape(lambda: tl_lib.init_state(
-        CAPACITY, N_PE, pending_capacity=CAPACITY, index_tile=tile))
+        capacity, n_pe, pending_capacity=capacity, index_tile=tile))
     reqs = [ARRequest(t_a=i, t_r=i, t_du=60, t_dl=i + 600, n_pe=32)
             for i in range(CHUNK)]
     batch = jax.eval_shape(lambda: batch_lib.requests_to_batch(reqs))
@@ -110,9 +128,13 @@ def test_admit_chunk_step_compiles_for_v5e(use_kernel, one_chip,
     state, batch = jax.tree_util.tree_map(place, (state, batch))
     scalar = _spec((), jnp.int32, one_chip)
     compiled = batch_lib.admit_stream_donated.lower(
-        state, batch, scalar, scalar, n_pe=N_PE, auto_release=True,
+        state, batch, scalar, scalar, n_pe=n_pe, auto_release=True,
         use_kernel=use_kernel).compile()
-    assert ("tpu_custom_call" in compiled.as_text()) == use_kernel
+    text = compiled.as_text()
+    assert ("tpu_custom_call" in text) == use_kernel
+    # the kernel reads the timeline packed: no [records, n_pe] matrix
+    # of the occupancy's bits exists outside it
+    assert not re.search(rf"\[{capacity},{n_pe}\]", text)
     jax.clear_caches()
 
 

@@ -5,14 +5,16 @@
   ``.candidates`` / ``.rects`` / ``.mask``, ``admit.commit``) in the
   lowered ``admit_stream_donated``, on the jnp and the kernel path.
 * The state's work counters (``n_early_rejects``, ``n_search_tiles``,
-  ``n_search_tiles_run``) equal a per-request brute-force recount of
-  ``summary_reject`` and of the kernel's live tiles, filler excluded;
+  ``n_search_tiles_run``, ``n_search_pe_blocks``) equal a per-request
+  brute-force recount of ``summary_reject``, of the kernel's live tiles
+  and of the PE blocks it ran for them, filler excluded;
   ``Session.metrics()`` reports them, ensembles sum their lanes, and
   they rewind on ``restore`` and round-trip through a checkpoint.
 * Decisions stay those of the host engine with the counters in place.
 * A profiler trace of a pipelined offer and its read-back holds the
   service's ``repro.*`` host spans, nested as documented.
 """
+import dataclasses
 import glob
 import os
 import random
@@ -32,7 +34,8 @@ from repro.core.types import ARRequest, Policy, T_INF
 from repro.kernels import availscan
 
 N_PE, CAPACITY, TILE, CHUNK = 64, 64, 8, 8
-COUNTERS = ("early_rejects", "search_tiles", "search_tiles_skipped")
+COUNTERS = ("early_rejects", "search_tiles", "search_tiles_skipped",
+            "search_pe_blocks")
 
 
 def _stream(n_jobs=60, seed=3):
@@ -69,15 +72,17 @@ def _decisions(res):
             np.asarray(dec.pe_mask)[v])
 
 
-def _brute_counts(jobs, use_kernel):
+def _brute_counts(jobs, use_kernel, n_pe=N_PE):
     """Recount per request, outside the scan: the index's early reject
     on the released state, and the kernel grid's tiles over the pruned
-    candidates of every request it does not reject."""
-    state = tl_lib.init_state(CAPACITY, N_PE, index_tile=TILE)
+    candidates of every request it does not reject, each live tile
+    running every PE block."""
+    state = tl_lib.init_state(CAPACITY, n_pe, index_tile=TILE)
     ispec = state.tl.ispec
     deficit = idx_lib.plane_deficit(ispec, None)
     rejects = tiles = run = 0
     pt = availscan.DEFAULT_PT
+    n_blocks = availscan.pe_blocks(tl_lib.n_words(n_pe))[1]
     for j in jobs:
         tl = batch_lib.release_due(state, jnp.int32(j.t_a)).tl
         demand = jnp.asarray([j.n_pe], jnp.int32)
@@ -94,11 +99,12 @@ def _brute_counts(jobs, use_kernel):
             run += sum(live[k * pt:(k + 1) * pt].any()
                        for k in range(n_tiles))
         state, _ = batch_lib.admit(
-            state, batch_lib.request_struct(j), jnp.int32(0), n_pe=N_PE,
+            state, batch_lib.request_struct(j), jnp.int32(0), n_pe=n_pe,
             use_kernel=use_kernel)
     assert not bool(state.overflow)
     return dict(early_rejects=rejects, search_tiles=tiles,
-                search_tiles_skipped=tiles - run)
+                search_tiles_skipped=tiles - run,
+                search_pe_blocks=run * n_blocks)
 
 
 @pytest.mark.parametrize("use_kernel", [False, True],
@@ -171,6 +177,34 @@ def test_ensemble_sums_lanes_and_partitions_report():
     assert m["search_tiles"] > 0
 
 
+@pytest.mark.parametrize("n_pe,block_words", [
+    (1024, availscan.BLOCK_WORDS), (320, 4)], ids=["one-block", "blocks"])
+def test_pe_block_counter_and_restore(n_pe, block_words, monkeypatch):
+    """One PE block per live tile at 1024 PEs, ``ceil(words / block)``
+    past one block (320 PEs in blocks of 4 words: three), and
+    ``restore`` rewinds the count."""
+    monkeypatch.setattr(availscan, "BLOCK_WORDS", block_words)
+    jax.clear_caches()
+    n_blocks = availscan.pe_blocks(tl_lib.n_words(n_pe))[1]
+    assert n_blocks == -(-tl_lib.n_words(n_pe) // block_words)
+    jobs = [dataclasses.replace(j, n_pe=j.n_pe * n_pe // N_PE)
+            for j in _stream(n_jobs=30)]
+    sess = ReservationService(_config(n_pe=n_pe)).session()
+    snap = sess.snapshot()
+    sess.offer(jobs)
+    m = sess.metrics()
+    run = m["search_tiles"] - m["search_tiles_skipped"]
+    assert m["search_pe_blocks"] == n_blocks * run > 0
+    if n_blocks > 1:
+        assert {k: m[k] for k in COUNTERS} == _brute_counts(jobs, True,
+                                                            n_pe)
+    sess.restore(snap)
+    assert sess.metrics()["search_pe_blocks"] == 0
+    sess.offer(jobs)
+    assert sess.metrics()["search_pe_blocks"] == m["search_pe_blocks"]
+    jax.clear_caches()
+
+
 def test_counters_round_trip_a_checkpoint(tmp_path):
     from repro.checkpoint import CheckpointManager
     jobs = _stream()
@@ -185,7 +219,7 @@ def test_counters_round_trip_a_checkpoint(tmp_path):
         CAPACITY, N_PE, index_tile=TILE))
     assert step == 1
     for f in ("n_early_rejects", "n_search_tiles", "n_search_tiles_run",
-              "n_accepted"):
+              "n_search_pe_blocks", "n_accepted"):
         assert int(getattr(back, f)) == int(getattr(state, f)), f
 
 
